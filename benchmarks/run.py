@@ -70,6 +70,8 @@ def main() -> None:
     ap.add_argument("--smoke", action="store_true",
                     help="small sizes/counts for CI (engine_throughput)")
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     reg = registry(smoke=args.smoke)
     names = args.only.split(",") if args.only else list(reg)
     failures = 0

@@ -20,6 +20,7 @@ import argparse
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import CoProcessor, uniform_relation, unique_relation
 from repro.engine import (FaultInjector, FaultSpec, JoinQuery,
                          JoinQueryService, QueryPlanner, QueueFull,
@@ -36,6 +37,7 @@ def result_rows(result):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--seed", type=int, default=7)
     ap.add_argument("--queries", type=int, default=12)

@@ -9,6 +9,7 @@ verifies every result against the oracle.
 import argparse
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import (CoProcessor, join_oracle, series_model_from_costs,
                         skewed_relation, uniform_relation, ICI_LINK)
 from repro.core.calibrate import APU_CPU, APU_GPU
@@ -16,6 +17,7 @@ from repro.core.shj import BUILD_SERIES, PROBE_SERIES
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--tuples", type=int, default=250_000)
     args = ap.parse_args()

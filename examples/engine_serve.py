@@ -11,11 +11,13 @@ against the oracle.
 import argparse
 import time
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import CoProcessor, join_oracle
 from repro.engine import JoinQueryService, QueryPlanner, make_workload
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--queries", type=int, default=24)
     ap.add_argument("--base-tuples", type=int, default=16384)

@@ -13,6 +13,7 @@ import argparse
 import json
 import os
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import CoProcessor, uniform_relation, unique_relation
 from repro.engine import (Backpressure, JoinQuery, JoinQueryService,
                           QueryPlanner, Tenant)
@@ -20,6 +21,7 @@ from repro.obs import FlightRecorder, validate_dump
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--out-dir", default="dumps")
     ap.add_argument("--rows", type=int, default=16384)

@@ -12,12 +12,14 @@ Run:  PYTHONPATH=src python examples/groupby_query.py
 """
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.engine import JoinQueryService, QueryPlanner
 from repro.queries import (JoinOrderOptimizer, PipelineExecutor,
                            make_star_query, reference_execute)
 
 
 def main():
+    enable_compile_cache()
     query = make_star_query(
         1 << 15, [2048, 1024], selectivities=[0.2, 0.5], seed=7,
         join_kinds=["inner", "semi"], group_by=("F.g",),

@@ -10,9 +10,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import MoECfg, all_configs, reduced
 from repro.layers.moe import moe_dense, moe_sorted, moe_specs
 from repro.models.params import materialize
+
+enable_compile_cache()
 
 cfg = reduced(all_configs()["granite_moe_3b"])
 cfg = dataclasses.replace(

@@ -11,6 +11,7 @@ against the pure-NumPy reference join.
 import argparse
 import time
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import CoProcessor
 from repro.engine import JoinQueryService, QueryPlanner
 from repro.queries import (JoinOrderOptimizer, PipelineExecutor,
@@ -18,6 +19,7 @@ from repro.queries import (JoinOrderOptimizer, PipelineExecutor,
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--fact-rows", type=int, default=65536)
     ap.add_argument("--dim-rows", type=int, default=8192)

@@ -4,10 +4,13 @@
 """
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import (CoProcessor, join_oracle, series_model_from_costs,
                         uniform_relation, unique_relation, ICI_LINK)
 from repro.core.calibrate import APU_CPU, APU_GPU
 from repro.core.shj import PROBE_SERIES
+
+enable_compile_cache()
 
 # 1. Data: build side R (unique keys), probe side S.
 R = unique_relation(100_000, seed=0)

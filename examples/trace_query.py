@@ -14,6 +14,7 @@ prediction-error ratios (measured/estimated, p50/p95) from the same run.
 """
 import argparse
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import CoProcessor
 from repro.engine import JoinQueryService, QueryPlanner
 from repro.queries import (JoinOrderOptimizer, PipelineExecutor,
@@ -21,6 +22,7 @@ from repro.queries import (JoinOrderOptimizer, PipelineExecutor,
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--out", default="trace.json")
     ap.add_argument("--fact-rows", type=int, default=65536)

@@ -15,6 +15,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.checkpoint import CheckpointManager
 from repro.configs import ShapeSpec, get_config
 from repro.data.pipeline import SyntheticLM
@@ -34,6 +35,7 @@ def config_100m():
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=300)
     ap.add_argument("--tiny", action="store_true")

@@ -572,7 +572,7 @@ class PhjCoProcessorMixin:
             slices = [(grp, grp.jit(
                 ("part_pass", tag, r.size, shift, bits, interpret),
                 partial(partition_pass, shift=shift, bits=bits,
-                        interpret=interpret))(r))
+                        interpret=interpret, mesh=grp.mesh))(r))
                 for grp, r in slices]
             shift += bits
         _maybe_fault("d2h")
@@ -593,7 +593,7 @@ class PhjCoProcessorMixin:
         ownership split for the join phase (paper PHJ-DD/PL skeleton).
 
         Pass knobs may be explicit or planner-chosen (``resolve_schedule``);
-        every pass runs the fused n1+n2 / scan+scatter data path.
+        every pass runs the fused n1+n2 / stable-sort n3 data path.
 
         ``partition_ratio`` — C-group share of the partition passes.
         ``join_ratio``      — fraction of partition pairs owned by C.
@@ -635,8 +635,9 @@ class PhjCoProcessorMixin:
         build_rel = self.pad_relation(build_rel, self.BUILD_PAD_KEY)
         probe_rel = self.pad_relation(probe_rel, self.PROBE_PAD_KEY)
 
-        def part_fn(rel):
-            return radix_partition_scheduled(rel, schedule=sched).rel
+        def part_fn(rel, mesh):
+            return radix_partition_scheduled(rel, schedule=sched,
+                                             mesh=mesh).rel
 
         with timing.phase("partition", passes=len(sched)):
             parts = {}
@@ -672,11 +673,12 @@ class PhjCoProcessorMixin:
                     self._bus_delay((n - cut) * 8, timing)
                 pieces = []
                 if cut > 0:
-                    f = self.c.jit(("phj_part", tag, cut, sched), part_fn)
+                    f = self.c.jit(("phj_part", tag, cut, sched),
+                                   partial(part_fn, mesh=self.c.mesh))
                     pieces.append(f(self.c.put_items(rel.take(0, cut))))
                 if cut < n:
                     f = self.g.jit(("phj_part", tag, n - cut, sched),
-                                   part_fn)
+                                   partial(part_fn, mesh=self.g.mesh))
                     pieces.append(f(self.g.put_items(rel.take(cut, n))))
                 _maybe_fault("d2h")
                 pieces = [jax.tree.map(jax.device_get, x) for x in pieces]

@@ -278,11 +278,10 @@ def join_oracle(build: Relation, probe: Relation) -> np.ndarray:
     lo = np.searchsorted(bk, pk, side="left")
     hi = np.searchsorted(bk, pk, side="right")
     counts = hi - lo
-    out = np.empty((counts.sum(), 2), dtype=np.int64)
-    w = 0
-    for i in np.nonzero(counts)[0]:
-        c = counts[i]
-        out[w:w + c, 0] = pr[i]
-        out[w:w + c, 1] = br[lo[i]:hi[i]]
-        w += c
+    # Pair j of probe i sits at output row starts[i] + j.
+    probe_of = np.repeat(np.arange(pk.shape[0]), counts)
+    starts = np.cumsum(counts) - counts
+    within = np.arange(probe_of.shape[0]) - starts[probe_of]
+    out = np.stack([pr[probe_of].astype(np.int64),
+                    br[lo[probe_of] + within].astype(np.int64)], axis=1)
     return out[np.lexsort((out[:, 1], out[:, 0]))]

@@ -68,17 +68,21 @@ def partition_n3(rel: Relation, pid: jax.Array) -> Relation:
     return Relation(rel.rid[order], rel.key[order])
 
 
-@partial(jax.jit, static_argnames=("schedule", "use_pallas", "interpret"))
+@partial(jax.jit, static_argnames=("schedule", "use_pallas", "interpret",
+                                   "mesh"))
 def radix_partition_scheduled(rel: Relation, *, schedule: tuple[int, ...],
                               use_pallas: bool | None = None,
-                              interpret: bool = False) -> Partitions:
+                              interpret: bool = False,
+                              mesh=None) -> Partitions:
     """Multi-pass radix partitioning over an explicit pass ``schedule``.
 
     ``schedule`` lists each pass's digit width, low digit first (a
     ``PassPlan.schedule`` — see ``repro.core.pass_planner``).  Every pass
     is the FUSED data path (``repro.kernels.partition_hist.ops``): n1+n2
-    in one VMEM sweep, n3 as a fused scan+scatter; stable reorders make
+    in one VMEM sweep, n3 as a stable sort on pid; stable reorders make
     the final layout clustered by the complete ``sum(schedule)``-bit radix.
+    ``mesh`` names the device mesh ``rel`` is sharded over (see
+    ``fused_partition_pass``).
     """
     from repro.kernels.partition_hist.ops import fused_partition_pass
 
@@ -88,7 +92,7 @@ def radix_partition_scheduled(rel: Relation, *, schedule: tuple[int, ...],
     for bits in schedule:
         cur, _, _ = fused_partition_pass(cur, shift=shift, bits=bits,
                                          use_pallas=use_pallas,
-                                         interpret=interpret)
+                                         interpret=interpret, mesh=mesh)
         shift += bits
     full_pid = radix_of(cur.key, shift=0, bits=total_bits)
     start, count = partition_n2(full_pid, 1 << total_bits)
@@ -97,13 +101,13 @@ def radix_partition_scheduled(rel: Relation, *, schedule: tuple[int, ...],
 
 def partition_pass(rel: Relation, *, shift: int, bits: int,
                    use_pallas: bool | None = None,
-                   interpret: bool = False) -> Relation:
-    """One fused partition pass (n1+n2 sweep, scan+scatter n3)."""
+                   interpret: bool = False, mesh=None) -> Relation:
+    """One fused partition pass (n1+n2 sweep, stable-sort n3)."""
     from repro.kernels.partition_hist.ops import fused_partition_pass
 
     out, _, _ = fused_partition_pass(rel, shift=shift, bits=bits,
                                      use_pallas=use_pallas,
-                                     interpret=interpret)
+                                     interpret=interpret, mesh=mesh)
     return out
 
 

@@ -12,7 +12,7 @@ TPU-idiomatic replacement for atomic aggregation buckets).
 Tuples with ``gid == -1`` (pad sentinels) match no slot and contribute
 nothing.  The one-hot expansion is O(tile * num_slots) per tile, so this
 kernel targets the VMEM-resident per-partition working sets the planner
-produces; ``ops.py`` gates dispatch by size and falls back to the masked
+produces; ``ops.py`` gates dispatch by slot count and runs the masked
 ``jax.ops.segment_*`` path otherwise.
 
 Sum width: by default sums accumulate *wide* — exact int64 semantics
@@ -79,78 +79,99 @@ def wide_sums_to_int64(sm: np.ndarray) -> np.ndarray:
 
 
 def _seg_agg_kernel(gid_ref, val_ref, cnt_ref, sum_ref, mn_ref, mx_ref, *,
-                    num_slots: int, wrap32: bool, chunk_bits: int = 8):
-    i = pl.program_id(0)
+                    wrap32: bool, chunk_bits: int, block_rows: int):
+    j = pl.program_id(0)
 
-    @pl.when(i == 0)
+    @pl.when(pl.program_id(1) == 0)
     def _init():
         cnt_ref[...] = jnp.zeros_like(cnt_ref)
         sum_ref[...] = jnp.zeros_like(sum_ref)
         mn_ref[...] = jnp.full_like(mn_ref, INT32_MAX)
         mx_ref[...] = jnp.full_like(mx_ref, INT32_MIN)
 
-    gid = gid_ref[...].reshape(-1)                         # (tile,)
-    val = val_ref[...].reshape(-1)
-    onehot = (gid[:, None] == jnp.arange(num_slots,
-                                         dtype=jnp.int32)[None, :])
-    oh32 = onehot.astype(jnp.int32)                        # (tile, S)
-    cnt_ref[...] += oh32.sum(axis=0)[None, :]
+    # Slots of this block on sublanes, tuples on lanes: each (1, 128) row
+    # of the tile broadcasts against it, so the tile is never flattened.
+    slot_block = cnt_ref.shape[0]
+    slot = (jax.lax.broadcasted_iota(jnp.int32, cnt_ref.shape, 0)
+            + j * slot_block)
+    gid = gid_ref[...]
+    val = val_ref[...]
     if wrap32:
-        sum_ref[...] += (val[:, None] * oh32).sum(axis=0)[None, :]
+        chans = [val]
     else:
         u = val.astype(jnp.uint32)
-        chunks = _num_chunks(chunk_bits)
-        for k in range(chunks):
-            chunk = ((u >> jnp.uint32(chunk_bits * k))
-                     & jnp.uint32((1 << chunk_bits) - 1)).astype(jnp.int32)
-            sum_ref[k, :] += (chunk[:, None] * oh32).sum(axis=0)
-        neg = (val < 0).astype(jnp.int32)
-        sum_ref[chunks, :] += (neg[:, None] * oh32).sum(axis=0)
-    mn_ref[...] = jnp.minimum(
-        mn_ref[...],
-        jnp.where(onehot, val[:, None], INT32_MAX).min(axis=0)[None, :])
-    mx_ref[...] = jnp.maximum(
-        mx_ref[...],
-        jnp.where(onehot, val[:, None], INT32_MIN).max(axis=0)[None, :])
+        chans = [((u >> jnp.uint32(chunk_bits * k))
+                  & jnp.uint32((1 << chunk_bits) - 1)).astype(jnp.int32)
+                 for k in range(_num_chunks(chunk_bits))]
+        chans.append((val < 0).astype(jnp.int32))
+    cnt = cnt_ref[...]
+    sums = [sum_ref[c] for c in range(len(chans))]
+    mn = mn_ref[...]
+    mx = mx_ref[...]
+    for r in range(block_rows):
+        hit = gid[r:r + 1, :] == slot                      # (slots, 128)
+        v = val[r:r + 1, :]
+        cnt += hit.astype(jnp.int32)
+        for c, ch in enumerate(chans):
+            sums[c] += jnp.where(hit, ch[r:r + 1, :], 0)
+        mn = jnp.minimum(mn, jnp.where(hit, v, INT32_MAX))
+        mx = jnp.maximum(mx, jnp.where(hit, v, INT32_MIN))
+    cnt_ref[...] = cnt
+    for c in range(len(chans)):
+        sum_ref[c] = sums[c]
+    mn_ref[...] = mn
+    mx_ref[...] = mx
+
+
+# Slots per grid block: one block's per-lane accumulators (count, min,
+# max and up to nine sum channels, 128 KiB each) stay well inside VMEM.
+SLOT_BLOCK = 256
 
 
 @functools.partial(jax.jit,
                    static_argnames=("num_slots", "block_rows", "interpret",
-                                    "wrap32"))
+                                    "wrap32", "chunk_bits"))
 def seg_agg_pallas(gid: jax.Array, val: jax.Array, *, num_slots: int,
                    block_rows: int = 8, interpret: bool = False,
-                   wrap32: bool = False):
+                   wrap32: bool = False, chunk_bits: int | None = None):
     """gid/val: (n,) int32, n % (block_rows*128) == 0; gid in [-1, num_slots).
 
     Returns ``(count, sum, min, max)``: count/min/max are ``(num_slots,)``
     int32; sum is ``(chunks+1, num_slots)`` wide channels by default
-    (chunk width adapted to ``n``; decode with ``wide_sums_to_int64``) or
-    ``(num_slots,)`` wrapping int32 under ``wrap32=True``.  Empty slots
-    report count 0, sum 0, min INT32_MAX, max INT32_MIN (neutral
-    elements).
+    (chunk width adapted to ``n`` unless ``chunk_bits`` is given; decode
+    with ``wide_sums_to_int64``) or ``(num_slots,)`` wrapping int32 under
+    ``wrap32=True``.  Empty slots report count 0, sum 0, min INT32_MAX,
+    max INT32_MIN (neutral elements).
+
+    Grid: slot blocks (outer) x tuple tiles (inner).  Every accumulator is
+    kept per lane, ``(slots, 128)``, and folded over its lanes after the
+    kernel.
     """
     n = gid.shape[0]
     lanes = 128
     rows = n // lanes
     assert rows % block_rows == 0 and n == rows * lanes, (n, block_rows)
-    grid = (rows // block_rows,)
-    chunk_bits = 8 if wrap32 else wide_chunk_bits(n)
+    if chunk_bits is None:
+        chunk_bits = wide_chunk_bits(n)
     sum_rows = 1 if wrap32 else _num_chunks(chunk_bits) + 1
-    out = pl.pallas_call(
-        functools.partial(_seg_agg_kernel, num_slots=num_slots,
-                          wrap32=wrap32, chunk_bits=chunk_bits),
-        grid=grid,
-        in_specs=[pl.BlockSpec((block_rows, lanes), lambda i: (i, 0)),
-                  pl.BlockSpec((block_rows, lanes), lambda i: (i, 0))],
-        out_specs=[pl.BlockSpec((1, num_slots), lambda i: (0, 0)),
-                   pl.BlockSpec((sum_rows, num_slots), lambda i: (0, 0)),
-                   pl.BlockSpec((1, num_slots), lambda i: (0, 0)),
-                   pl.BlockSpec((1, num_slots), lambda i: (0, 0))],
-        out_shape=[jax.ShapeDtypeStruct((1, num_slots), jnp.int32),
-                   jax.ShapeDtypeStruct((sum_rows, num_slots), jnp.int32),
-                   jax.ShapeDtypeStruct((1, num_slots), jnp.int32),
-                   jax.ShapeDtypeStruct((1, num_slots), jnp.int32)],
+    sb = min(SLOT_BLOCK, -(-num_slots // 8) * 8)
+    slots = -(-num_slots // sb) * sb
+    acc = jax.ShapeDtypeStruct((slots, lanes), jnp.int32)
+    acc_spec = pl.BlockSpec((sb, lanes), lambda j, i: (j, 0))
+    tile_spec = pl.BlockSpec((block_rows, lanes), lambda j, i: (i, 0))
+    cnt, sm, mn, mx = pl.pallas_call(
+        functools.partial(_seg_agg_kernel, wrap32=wrap32,
+                          chunk_bits=chunk_bits, block_rows=block_rows),
+        grid=(slots // sb, rows // block_rows),
+        in_specs=[tile_spec, tile_spec],
+        out_specs=[acc_spec,
+                   pl.BlockSpec((sum_rows, sb, lanes), lambda j, i: (0, j, 0)),
+                   acc_spec, acc_spec],
+        out_shape=[acc,
+                   jax.ShapeDtypeStruct((sum_rows, slots, lanes), jnp.int32),
+                   acc, acc],
         interpret=interpret,
     )(gid.reshape(rows, lanes), val.reshape(rows, lanes))
-    cnt, sm, mn, mx = out
-    return cnt[0], (sm[0] if wrap32 else sm), mn[0], mx[0]
+    sm = sm.sum(axis=-1)[:, :num_slots]
+    return (cnt.sum(axis=-1)[:num_slots], sm[0] if wrap32 else sm,
+            mn.min(axis=-1)[:num_slots], mx.max(axis=-1)[:num_slots])

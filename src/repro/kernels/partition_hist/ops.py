@@ -1,58 +1,65 @@
-"""Dispatchers for the radix-partition kernels.
+"""Dispatcher for the radix-partition kernels.
 
 ``fused_partition_pass`` is the data path behind one radix pass everywhere
-(``repro.core.partition`` routes through it): the fused n1+n2 kernel plus
-the fused scan+scatter n3 kernel on TPU-shaped inputs, and an equivalent
-single-dispatch jnp path (one pid computation feeding histogram, scan and
-stable reorder — no re-materialization between steps) elsewhere.
+(``repro.core.partition`` routes through it): one pid computation feeding
+histogram, scan and a stable reorder.  On TPU-shaped inputs the pid and
+histogram come from the fused n1+n2 Pallas kernel; elsewhere from the
+equivalent jnp oracle.  n3 is the stable ``argsort`` on pid at every size.
 """
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from .fused import partition_hist_fused_pallas
-from .partition_hist import radix_hist_pallas
-from .ref import partition_hist_fused_ref, radix_hist_ref
-from .reorder import radix_scatter_pallas
+from .ref import partition_hist_fused_ref
 
-# The one-hot scatter kernel keeps the whole output in VMEM; beyond this
-# many tuples the fused jnp path wins (and always on non-TPU backends).
-_SCATTER_VMEM_LIMIT = 1 << 17
-
-
-def radix_hist(pid, *, num_parts: int, use_pallas: bool | None = None):
-    if use_pallas is None:
-        use_pallas = jax.default_backend() == "tpu"
-    if use_pallas and pid.shape[0] % 1024 == 0:
-        return radix_hist_pallas(pid, num_parts=num_parts)
-    return radix_hist_ref(pid, num_parts=num_parts)
+# Size gate of the fused kernel (not yet set from a chip measurement);
+# the jnp path runs above it.
+_FUSED_MAX_TUPLES = 1 << 17
+# The one-hot histogram costs 2**bits compares per tuple; wider digits
+# take the jnp path.
+_FUSED_MAX_BITS = 8
 
 
 def fused_partition_pass(rel, *, shift: int, bits: int,
                          use_pallas: bool | None = None,
-                         interpret: bool = False):
-    """One full radix pass (n1+n2+n3 fused).
+                         interpret: bool = False, mesh=None):
+    """One full radix pass (n1+n2 fused, then n3).
 
     Returns ``(reordered Relation, starts, counts)`` for the ``bits``-wide
     digit at ``shift``; the reorder is a stable clustering by that digit.
+    ``mesh``: the device mesh (axis ``"i"``) the relation is sharded over
+    inside the calling program; the kernel then runs per shard under
+    ``shard_map`` and the shard histograms are summed.
     """
     from repro.core.relation import Relation
 
     n = rel.key.shape[0]
-    num_parts = 1 << bits
+    shards = 1 if mesh is None else mesh.size
     if use_pallas is None:
         use_pallas = (jax.default_backend() == "tpu"
-                      and n <= _SCATTER_VMEM_LIMIT)
-    if (use_pallas or interpret) and n % 1024 == 0:
-        pid, counts = partition_hist_fused_pallas(
-            rel.key, shift=shift, bits=bits, interpret=interpret)
-        starts = jnp.cumsum(counts) - counts
-        rid, key = radix_scatter_pallas(rel.rid, rel.key, pid,
-                                        starts.astype(jnp.int32),
-                                        num_parts=num_parts,
-                                        interpret=interpret)
-        return Relation(rid, key), starts, counts
-    # Fused jnp path: pid computed once, shared by histogram and reorder.
-    pid, counts = partition_hist_fused_ref(rel.key, shift=shift, bits=bits)
+                      and n <= _FUSED_MAX_TUPLES and bits <= _FUSED_MAX_BITS)
+    if use_pallas or interpret:
+        def pid_hist(keys):
+            pid, hist = partition_hist_fused_pallas(
+                keys, shift=shift, bits=bits, interpret=interpret)
+            return pid, (hist if mesh is None else jax.lax.psum(hist, "i"))
+
+        if mesh is not None:
+            pid_hist = jax.shard_map(pid_hist, mesh=mesh, in_specs=P("i"),
+                                     out_specs=(P("i"), P()),
+                                     check_vma=False)
+        # Whole (8, 128) tiles per shard: zero keys pad the tail, and
+        # their pids and counts are taken back out.
+        pad = -n % (1024 * shards)
+        pid, counts = pid_hist(jnp.pad(rel.key, (0, pad)))
+        if pad:
+            pid = pid[:n]
+            counts = counts - partition_hist_fused_ref(
+                jnp.zeros((pad,), rel.key.dtype), shift=shift, bits=bits)[1]
+    else:
+        pid, counts = partition_hist_fused_ref(rel.key, shift=shift,
+                                               bits=bits)
     starts = jnp.cumsum(counts) - counts
     order = jnp.argsort(pid, stable=True)
     return Relation(rel.rid[order], rel.key[order]), starts, counts

@@ -13,14 +13,3 @@ def partition_hist_fused_ref(keys, *, shift: int, bits: int):
     from repro.core.relation import radix_of
     pid = radix_of(keys, shift=shift, bits=bits)
     return pid, radix_hist_ref(pid, num_parts=1 << bits)
-
-
-def radix_scatter_ref(rid, key, pid, starts=None, *, num_parts: int = 0):
-    """Oracle for the fused n3 kernel: stable reorder of tuples by pid.
-
-    ``dest[i] = starts[pid[i]] + rank_of_i_within_its_partition`` is exactly
-    the inverse of the stable argsort permutation, so the oracle is the
-    stable sort itself (``starts`` is accepted for signature parity).
-    """
-    order = jnp.argsort(pid, stable=True)
-    return rid[order], key[order]

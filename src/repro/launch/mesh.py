@@ -13,22 +13,18 @@ from __future__ import annotations
 import jax
 
 
-def make_mesh_compat(shape, axes, devices=None):
-    """``jax.make_mesh`` with explicit Auto axis types where supported.
-
-    axis_types / AxisType only exist on newer jax; explicit Auto is the
-    default there, so older versions just omit it.
-    """
+def make_auto_mesh(shape, axes, devices=None):
+    """``jax.make_mesh`` with every axis of type Auto."""
     kw = {"devices": devices} if devices is not None else {}
-    if hasattr(jax.sharding, "AxisType"):
-        kw["axis_types"] = (jax.sharding.AxisType.Auto,) * len(axes)
-    return jax.make_mesh(shape, axes, **kw)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+                         **kw)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return make_auto_mesh(shape, axes)
 
 
 def make_host_mesh(model: int | None = None):
@@ -36,7 +32,7 @@ def make_host_mesh(model: int | None = None):
     n = len(jax.devices())
     model = model or 1
     data = n // model
-    return make_mesh_compat((data, model), ("data", "model"))
+    return make_auto_mesh((data, model), ("data", "model"))
 
 
 # v5e hardware constants (per chip) — used by roofline + cost model.

@@ -2,7 +2,7 @@
 
 Group-by shares the join's partition/probe cost structure (Shanbhag et
 al.): cluster the group keys with the SAME fused radix passes PHJ uses
-(n1+n2 one-VMEM-pass, scan+scatter n3), then reduce each partition's
+(n1+n2 one-VMEM-pass, stable-sort n3), then reduce each partition's
 VMEM-resident tuples.  The co-processing skeleton mirrors ``CoProcessor.
 phj`` one-to-one:
 
@@ -85,10 +85,10 @@ class GroupByResult:
 
 
 @partial(jax.jit, static_argnames=("num_slots", "use_pallas", "interpret",
-                                   "wrap32"))
+                                   "wrap32", "mesh"))
 def grouped_agg(rel: Relation, values: jax.Array, *, num_slots: int,
                 use_pallas: bool | None = None, interpret: bool = False,
-                wrap32: bool = False):
+                wrap32: bool = False, mesh=None):
     """One group's aggregation: sort by key, flag boundaries, reduce.
 
     ``values[i]`` belongs to tuple ``i`` of ``rel``; pad tuples are marked
@@ -97,7 +97,8 @@ def grouped_agg(rel: Relation, values: jax.Array, *, num_slots: int,
     ``g``-th distinct key in (uint32) sorted order; slots past
     ``num_groups`` report count 0.  ``sum`` is the kernel's (5, slots)
     wide-channel layout by default (``wide_sums_to_int64`` decodes) or a
-    wrapping int32 vector under ``wrap32=True``.
+    wrapping int32 vector under ``wrap32=True``.  ``mesh`` names the
+    device mesh ``rel`` is sharded over (see ``segmented_aggregate``).
     """
     n = rel.key.shape[0]
     order = jnp.argsort(rel.key.astype(jnp.uint32), stable=True)
@@ -112,7 +113,8 @@ def grouped_agg(rel: Relation, values: jax.Array, *, num_slots: int,
                      jnp.int32).at[jnp.clip(gid, 0, num_slots - 1)].set(skey)
     cnt, sm, mn, mx = segmented_aggregate(
         jnp.where(valid, gid, -1), svals, num_slots=num_slots,
-        use_pallas=use_pallas, interpret=interpret, wrap32=wrap32)
+        use_pallas=use_pallas, interpret=interpret, wrap32=wrap32,
+        mesh=mesh)
     num_groups = (first & valid).astype(jnp.int32).sum()
     return ukeys, cnt, sm, mn, mx, num_groups
 
@@ -219,9 +221,10 @@ def groupby_coprocessed(cp: CoProcessor, rel: Relation, values, *,
         timing.notes["schedule"] = list(schedule)
         total_bits = sum(schedule)
 
-        def part_fn(r):
+        def part_fn(r, mesh):
             return radix_partition_scheduled(r, schedule=schedule,
-                                             interpret=interpret).rel
+                                             interpret=interpret,
+                                             mesh=mesh).rel
 
         with timing.phase("partition", passes=len(schedule)):
             if ctx is not None:
@@ -235,10 +238,12 @@ def groupby_coprocessed(cp: CoProcessor, rel: Relation, values, *,
                     cp._bus_delay((n - cut) * 8, timing)
                 pieces = []
                 if cut > 0:
-                    f = cp.c.jit(("gb_part", cut, schedule), part_fn)
+                    f = cp.c.jit(("gb_part", cut, schedule),
+                                 partial(part_fn, mesh=cp.c.mesh))
                     pieces.append(f(cp.c.put_items(rel.take(0, cut))))
                 if cut < n:
-                    f = cp.g.jit(("gb_part", n - cut, schedule), part_fn)
+                    f = cp.g.jit(("gb_part", n - cut, schedule),
+                                 partial(part_fn, mesh=cp.g.mesh))
                     pieces.append(f(cp.g.put_items(rel.take(cut, n))))
                 pieces = [jax.tree.map(jax.device_get, x) for x in pieces]
                 rel = Relation(jnp.concatenate([x.rid for x in pieces]),
@@ -269,7 +274,8 @@ def groupby_coprocessed(cp: CoProcessor, rel: Relation, values, *,
                 vals = _gather_values(values, rid)
                 f = grp.jit(("gb_agg", m, interpret, wrap32),
                             partial(grouped_agg, num_slots=m,
-                                    interpret=interpret, wrap32=wrap32))
+                                    interpret=interpret, wrap32=wrap32,
+                                    mesh=grp.mesh))
                 outs.append(f(grp.put_items(Relation(jnp.asarray(rid),
                                                      jnp.asarray(key))),
                               grp.put_items(jnp.asarray(vals))))
@@ -294,7 +300,8 @@ def groupby_coprocessed(cp: CoProcessor, rel: Relation, values, *,
                 for grp, lo, hi in ((cp.c, 0, cut), (cp.g, cut, n)):
                     f = grp.jit(("gb_agg", hi - lo, interpret, wrap32),
                                 partial(grouped_agg, num_slots=hi - lo,
-                                        interpret=interpret, wrap32=wrap32))
+                                        interpret=interpret, wrap32=wrap32,
+                                        mesh=grp.mesh))
                     outs.append(f(grp.put_items(rel.take(lo, hi)),
                                   grp.put_items(jnp.asarray(vals[lo:hi]))))
             else:
@@ -304,7 +311,8 @@ def groupby_coprocessed(cp: CoProcessor, rel: Relation, values, *,
                 vals = _gather_values(values, np.asarray(rel.rid))
                 f = grp.jit(("gb_agg", n, interpret, wrap32),
                             partial(grouped_agg, num_slots=n,
-                                    interpret=interpret, wrap32=wrap32))
+                                    interpret=interpret, wrap32=wrap32,
+                                    mesh=grp.mesh))
                 outs = [f(grp.put_items(rel),
                           grp.put_items(jnp.asarray(vals)))]
             outs = [jax.tree.map(jax.device_get, o) for o in outs]

@@ -55,8 +55,8 @@ def test_elastic_restore_to_new_sharding(tmp_path):
     the any-topology restore path (DESIGN.md §5)."""
     t = _tree()
     save_checkpoint(str(tmp_path), 9, t)
-    from repro.launch.mesh import make_mesh_compat
-    mesh = make_mesh_compat((1,), ("data",))
+    from repro.launch.mesh import make_auto_mesh
+    mesh = make_auto_mesh((1,), ("data",))
     sh = jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec("data"))
     shardings = {"w": {"a": sh, "b": sh},
                  "step": jax.sharding.NamedSharding(

@@ -95,8 +95,8 @@ def test_ssd_chunked_equals_recurrence():
 
 def test_sharding_rule_engine():
     from repro.distributed.sharding import TRAIN_RULES, axes_to_spec
-    from repro.launch.mesh import make_mesh_compat
-    mesh = make_mesh_compat((1, 1), ("data", "model"))
+    from repro.launch.mesh import make_auto_mesh
+    mesh = make_auto_mesh((1, 1), ("data", "model"))
     # engine falls back to None when sizes don't divide
     spec = axes_to_spec(("batch", "heads"), (3, 5), TRAIN_RULES, mesh)
     assert spec == jax.sharding.PartitionSpec(None, None) or all(

@@ -39,8 +39,8 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import json, dataclasses, jax, numpy as np
 from repro.configs import all_configs, reduced, SHAPES, ShapeSpec
 from repro.launch import dryrun as dr
-from repro.launch.mesh import make_mesh_compat
-mesh = make_mesh_compat((2, 4), ("data", "model"))
+from repro.launch.mesh import make_auto_mesh
+mesh = make_auto_mesh((2, 4), ("data", "model"))
 cfg = reduced(all_configs()["qwen3_8b"])
 cfg = dataclasses.replace(cfg, d_model=64, num_heads=8, num_kv_heads=4,
                           head_dim=16, d_ff=128)

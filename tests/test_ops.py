@@ -46,7 +46,8 @@ def run_pipeline(query, physical=None, optimizer=None, num_workers=2):
 # Segmented-aggregation kernel: interpret-mode Pallas vs jnp oracle.
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("n,slots", [(1024, 16), (2048, 128), (8192, 1024)])
+@pytest.mark.parametrize("n,slots", [(1024, 16), (2048, 128), (8192, 1024),
+                                     (2048, 300), (1024, 5)])
 def test_seg_agg_kernel(n, slots, rng):
     from repro.kernels.agg.agg import seg_agg_pallas
     from repro.kernels.agg.ref import seg_agg_ref
@@ -54,6 +55,22 @@ def test_seg_agg_kernel(n, slots, rng):
     val = jnp.asarray(rng.integers(-1000, 1000, n).astype(np.int32))
     got = seg_agg_pallas(gid, val, num_slots=slots, interpret=True)
     exp = seg_agg_ref(gid, val, num_slots=slots)
+    for g, e, name in zip(got, exp, ("count", "sum", "min", "max")):
+        assert (np.asarray(g) == np.asarray(e)).all(), name
+
+
+@pytest.mark.parametrize("wrap32", [False, True])
+@pytest.mark.parametrize("n", [1000, 3000])
+def test_segmented_aggregate_pads_to_tiles(n, wrap32, rng):
+    """The dispatcher pads sizes off the 1024 tile with gid -1 tuples;
+    the kernel's answer is the jnp path's, channel layout included."""
+    from repro.kernels.agg.ops import segmented_aggregate
+    gid = jnp.asarray(rng.integers(-1, 64, n).astype(np.int32))
+    val = jnp.asarray(rng.integers(-2**31, 2**31 - 1, n).astype(np.int32))
+    got = segmented_aggregate(gid, val, num_slots=64, interpret=True,
+                              wrap32=wrap32)
+    exp = segmented_aggregate(gid, val, num_slots=64, use_pallas=False,
+                              wrap32=wrap32)
     for g, e, name in zip(got, exp, ("count", "sum", "min", "max")):
         assert (np.asarray(g) == np.asarray(e)).all(), name
 
