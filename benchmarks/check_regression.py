@@ -121,7 +121,8 @@ def main() -> int:
                         f"{fused_bytes}")
 
     # -- data-path observability: cardinality audit present + finite ------
-    KNOWN_CAUSES = ("fingerprint", "multicol_pack", "handoff", "result")
+    KNOWN_CAUSES = ("fingerprint", "multicol_pack", "handoff", "result",
+                    "exchange", "base_upload")
     INTERMEDIATE = ("fingerprint", "multicol_pack", "handoff")
     card = payload.get("cardinality") or {}
     if not card.get("count") or not card.get("stage_types"):

@@ -12,8 +12,18 @@ from .common import N_TUPLES, csv_row, default_relations, report, time_call
 
 def _coprocessors():
     from repro.core import CoProcessor, PCIE_LINK
-    return (CoProcessor(),                                   # coupled
-            CoProcessor(link=PCIE_LINK, discrete=True))      # discrete(em.)
+    from repro.obs import Tracer
+    return (CoProcessor(tracer=Tracer()),                    # coupled
+            CoProcessor(link=PCIE_LINK, discrete=True,       # discrete(em.)
+                        tracer=Tracer()))
+
+
+def _take_merge_s(cp) -> float:
+    """Seconds in the separate-table ``merge`` spans since the last call
+    (the tracer is cleared)."""
+    s = sum(r.t1 - r.t0 for r in cp.tracer.spans() if r.name == "merge")
+    cp.tracer.clear()
+    return s
 
 
 def _model_for(series, n_items, *, device_pair="apu", link="zerocopy",
@@ -43,12 +53,13 @@ def fig3_time_breakdown():
                         build_ratios=[0.25] * 4, probe_ratios=[0.42] * 4,
                         table_mode="separate" if label == "discrete"
                         else "shared")
+        merge_s = _take_merge_s(cp)
         out[f"shj_dd_{label}"] = {
             "build_s": t.phase_s["build"], "probe_s": t.phase_s["probe"],
-            "merge_s": t.merge_s, "transfer_s": t.transfer_s,
+            "merge_s": merge_s, "transfer_s": t.transfer_s,
             "transfer_bytes": t.transfer_bytes, "wall_s": t.wall_s}
         csv_row(f"fig3/shj_dd_{label}", t.wall_s * 1e6,
-                f"merge={t.merge_s:.3f}s;xfer={t.transfer_s:.3f}s")
+                f"merge={merge_s:.3f}s;xfer={t.transfer_s:.3f}s")
     d, c = out["shj_dd_discrete"], out["shj_dd_coupled"]
     out["merge_pct_discrete"] = 100 * d["merge_s"] / d["wall_s"]
     out["transfer_pct_discrete"] = 100 * d["transfer_s"] / d["wall_s"]
@@ -190,7 +201,8 @@ def fig9_monte_carlo():
 def fig10_shared_vs_separate():
     """Fig. 10: build phase with shared vs separate hash tables."""
     from repro.core import CoProcessor
-    cp = CoProcessor()
+    from repro.obs import Tracer
+    cp = CoProcessor(tracer=Tracer())
     b, s = default_relations(N_TUPLES // 2)
     nb = max(1024, N_TUPLES // 8)
     out = {}
@@ -198,9 +210,10 @@ def fig10_shared_vs_separate():
         _, t = cp.shj(b, s, num_buckets=nb, max_out=2 * b.size,
                       build_ratios=[0.25] * 4, probe_ratios=[0.42] * 4,
                       table_mode=mode)
-        out[mode] = {"build_s": t.phase_s["build"], "merge_s": t.merge_s}
+        merge_s = _take_merge_s(cp)
+        out[mode] = {"build_s": t.phase_s["build"], "merge_s": merge_s}
         csv_row(f"fig10/{mode}", t.phase_s["build"] * 1e6,
-                f"merge={t.merge_s:.3f}s")
+                f"merge={merge_s:.3f}s")
     out["shared_speedup_pct"] = 100 * (1 - out["shared"]["build_s"]
                                        / out["separate"]["build_s"])
     report("fig10_shared_separate", out)

@@ -11,7 +11,10 @@ breaker short-circuit, no recovery event.
     python chip_smoke.py --chips 4 [--seed N]
 
 One chip (the default): k) the main-path Pallas kernels, lowered and run
-against their jnp paths; a) a cold 16M x 16M uniform equi-join; b) a mixed
+against their jnp paths; a) a cold 16M x 16M uniform equi-join, with the
+operator's view of it: the service's span totals by name (with the
+compile seconds inside them), its transfer-ledger bytes by cause and its
+host syncs by site; b) a mixed
 stream of joins over 64k-1M tuple relations with hot-table repeats; c) a
 3-join star over a 16M-row fact table whose filtered dimensions leave the
 group-by + sum sink few enough rows for the aggregation kernel.
@@ -176,11 +179,14 @@ def phase_paper_join(svc, seed: int, clock: CompileClock, *, tag: str,
     q = JoinQuery(build=build, probe=probe, tag="paper16M",
                   max_out=PAPER_TUPLES + PAPER_TUPLES // 4, query_id=1)
     clock.take()
+    mark = (svc.tracer.now(), svc.ledger.by_cause(),
+            svc.metrics.counter_series("host_syncs"))
     t0 = time.perf_counter()
     (o,) = svc.run([q])
     block(o.result)
     wall = time.perf_counter() - t0
     compile_s = clock.take()
+    obs_lines(svc, tag, *mark)
     if expected is None:
         expected = join_oracle(build, probe)
     if not np_equal(o.result.valid_pairs(), expected):
@@ -193,6 +199,32 @@ def phase_paper_join(svc, seed: int, clock: CompileClock, *, tag: str,
     phase_line(tag, wall, int(o.result.count), plan_str([o]), compile_s,
                pairs_checked=len(expected))
     return [o], expected
+
+
+def obs_lines(svc, tag: str, since: float, ledger0: dict,
+              syncs0: dict) -> None:
+    """What the service recorded since ``since``: span totals by name
+    (count, seconds, compile seconds inside), ledger MiB by cause, host
+    syncs by site."""
+    totals: dict = {}
+    for r in svc.tracer.spans():
+        if r.lane is None and r.t0 >= since:
+            n, sec, comp = totals.get(r.name, (0, 0.0, 0.0))
+            totals[r.name] = (n + 1, sec + r.t1 - r.t0,
+                              comp + r.attrs.get("compile_s", 0.0))
+    for name, (n, sec, comp) in sorted(totals.items(),
+                                       key=lambda kv: -kv[1][1]):
+        print(f"phase {tag} span {name}: count={n} total_s={sec:.3f} "
+              f"compile_s={comp:.3f}", flush=True)
+    by = svc.ledger.by_cause()
+    print(f"phase {tag} ledger_mib: " + " ".join(
+        f"{c}={(by[c] - ledger0.get(c, 0)) / (1 << 20):.3f}" for c in by),
+        flush=True)
+    syncs = svc.metrics.counter_series("host_syncs")
+    print(f"phase {tag} host_syncs: " + " ".join(
+        f"{dict(k)['site']}={int(v - syncs0.get(k, 0))}"
+        for k, v in sorted(syncs.items()) if v > syncs0.get(k, 0)),
+        flush=True)
 
 
 def cache_hits(svc) -> int:

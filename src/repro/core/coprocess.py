@@ -65,7 +65,6 @@ class Timing:
     phase_s: dict = dataclasses.field(default_factory=dict)
     transfer_bytes: int = 0
     transfer_s: float = 0.0
-    merge_s: float = 0.0
     notes: dict = dataclasses.field(default_factory=dict)
     # Observability hook: phases timed through ``phase()`` also emit
     # tracer spans (nested under whatever query span the calling thread
@@ -103,17 +102,24 @@ class Timing:
             "phase_s": {k: float(v) for k, v in self.phase_s.items()},
             "transfer_bytes": int(self.transfer_bytes),
             "transfer_s": float(self.transfer_s),
-            "merge_s": float(self.merge_s),
             "notes": {k: (v if isinstance(v, (int, float, str, bool, list))
                           else str(v)) for k, v in self.notes.items()},
         }
 
 
 class DeviceGroup:
-    """A set of devices acting as one logical processor (C or G)."""
+    """A set of devices acting as one logical processor (C or G).
 
-    def __init__(self, name: str, devices):
+    ``owner`` (the ``CoProcessor``) lends its ``tracer`` to the group's
+    launches: each call of a ``jit`` program is a ``launch`` span naming
+    the program (``key[0]``) and the group, which closes with the
+    compiling done inside it (``compile_s`` / ``compiles``): the
+    per-program compile record.
+    """
+
+    def __init__(self, name: str, devices, owner=None):
         self.name = name
+        self.owner = owner
         self.devices = list(devices)
         if len(self.devices) > 1:
             self.mesh = jax.sharding.Mesh(np.array(self.devices), ("i",))
@@ -151,12 +157,20 @@ class DeviceGroup:
             if cached is None:
                 jf = jax.jit(fn)
 
-                def cached(*args, _jf=jf, **kw):
+                def cached(*args, _jf=jf, _program=str(key[0]), **kw):
                     _maybe_fault("kernel")   # launch-site fault injection
-                    return _jf(*args, **kw)
+                    return self._launch(_program, _jf, args, kw)
 
                 self._jit_cache[key] = cached
             return cached
+
+    def _launch(self, program: str, jf, args, kw):
+        from repro.obs import NULL_TRACER
+        tracer = getattr(self.owner, "tracer", NULL_TRACER)
+        if not tracer.enabled:
+            return jf(*args, **kw)
+        with tracer.span("launch", program=program, group=self.name):
+            return jf(*args, **kw)
 
 
 class CoProcessor:
@@ -169,11 +183,16 @@ class CoProcessor:
                  link: LinkSpec = ZEROCOPY_LINK, discrete: bool = False,
                  ratio_quantum: int = 64, tracer=None):
         # Observability: phase timings flow through ``Timing.phase`` and
-        # emit spans on this tracer.  The default is the shared no-op
-        # recorder, so a standalone CoProcessor pays one branch per
-        # phase; ``JoinQueryService`` swaps in its real tracer.
+        # emit spans on this tracer; host crossings go through
+        # ``self.boundary`` into ``ledger`` (cause ``exchange``) and
+        # ``metrics`` (``host_syncs``).  The defaults are the
+        # shared no-op recorder and no ledger or registry, so a standalone
+        # CoProcessor records nothing and pays one branch per span;
+        # ``JoinQueryService`` adopts all three.
         from repro.obs import NULL_TRACER
         self.tracer = tracer if tracer is not None else NULL_TRACER
+        self.ledger = None
+        self.metrics = None
         devs = jax.devices()
         if c_devices is None or g_devices is None:
             want_c = os.environ.get("REPRO_C_DEVICES")
@@ -186,8 +205,8 @@ class CoProcessor:
                 c_devices, g_devices = devs[:1], devs[1:]
             else:  # single device: both groups share it (functional mode)
                 c_devices = g_devices = devs[:1]
-        self.c = DeviceGroup("C", c_devices)
-        self.g = DeviceGroup("G", g_devices)
+        self.c = DeviceGroup("C", c_devices, owner=self)
+        self.g = DeviceGroup("G", g_devices, owner=self)
         # Per-group execution locks for concurrent callers (the engine's
         # worker threads).  Two sharded programs with collectives must
         # never interleave on the same device group — XLA's rendezvous
@@ -200,6 +219,21 @@ class CoProcessor:
         # Cuts and relation sizes are kept multiples of this, so both
         # groups' slices shard evenly over their devices.
         self.lcm = math.lcm(self.c.size, self.g.size)
+
+    @property
+    def boundary(self):
+        """Host crossings, recorded on this CoProcessor's tracer, ledger
+        and registry (cause ``exchange``)."""
+        from repro.obs import HostBoundary
+        return HostBoundary(self.tracer, self.ledger, self.metrics)
+
+    def _pull(self, x, site: str, column: str = "-"):
+        return self.boundary.pull(x, site=site, cause="exchange",
+                                  column=column)
+
+    def _push(self, x, place, site: str, column: str = "-"):
+        return self.boundary.push(x, place, site=site, cause="exchange",
+                                  column=column)
 
     BUILD_PAD_KEY = -2   # sentinel keys: pads never match real (>=0) keys
     PROBE_PAD_KEY = -3
@@ -391,17 +425,18 @@ class CoProcessor:
             part_c = fb_c(rel_c)
             part_g = fb_g(rel_g)
             jax.block_until_ready((part_c.rids, part_g.rids))
-            tm = time.perf_counter()
-            if self.discrete:  # ship the partial table back over the bus
-                self._bus_delay(sum(x.nbytes for x in
-                                    jax.tree.leaves(part_g)), timing)
-            part_g_host = jax.tree.map(jax.device_get, part_g)
-            fm = self.c.jit(("merge", n, num_buckets),
-                            partial(ht.merge_hash_tables,
-                                    num_buckets=num_buckets))
-            table = fm([part_c, self.c.put_shared(part_g_host)])
-            jax.block_until_ready(table.rids)
-            timing.merge_s = time.perf_counter() - tm
+            with self.tracer.span("merge"):
+                if self.discrete:  # ship the partial table back over the bus
+                    self._bus_delay(sum(x.nbytes for x in
+                                        jax.tree.leaves(part_g)), timing)
+                part_g_host = self._pull(part_g, "shj.merge", "table")
+                fm = self.c.jit(("merge", n, num_buckets),
+                                partial(ht.merge_hash_tables,
+                                        num_buckets=num_buckets))
+                table = fm([part_c, self._push(part_g_host,
+                                               self.c.put_shared,
+                                               "shj.merge", "table")])
+                jax.block_until_ready(table.rids)
             return table
         # Shared table (or degenerate single-group): bucket-range ownership.
         # C owns buckets [0, r1*B); each group receives its owned tuples and
@@ -417,31 +452,36 @@ class CoProcessor:
             table = fb(grp.put_items(rel))
             jax.block_until_ready(table.rids)
             return table
-        bkt = bucket_of(rel.key, num_buckets)
-        to_c = bkt < own_c
-        order = jnp.argsort(~to_c, stable=True)  # owners contiguous
-        n_c = int(to_c.sum())
-        srel = Relation(rel.rid[order], rel.key[order])
-        # Exchange: tuples cross groups to reach their owner (bounded above
-        # by the full relation; discrete pays the bus for the crossing part).
-        crossing = min(n_c, n - cut) + min(n - n_c, cut)
-        self._bus_delay(crossing * 8, timing)
-        n_c_pad = _round_up(max(n_c, 1), self.lcm)
-        n_g_pad = _round_up(max(n - n_c, 1), self.lcm)
-        rel_c = self.c.put_items(_pad_slice(srel, 0, n_c, n_c_pad,
-                                            self.BUILD_PAD_KEY))
-        rel_g = self.g.put_items(_pad_slice(srel, n_c, n, n_g_pad,
-                                            self.BUILD_PAD_KEY))
+        with self.tracer.span("exchange"):
+            bkt = bucket_of(rel.key, num_buckets)
+            to_c = bkt < own_c
+            order = jnp.argsort(~to_c, stable=True)  # owners contiguous
+            n_c = int(self.boundary.pull(to_c.sum(), site="shj.build.owners",
+                                         cause=None))
+            srel = Relation(rel.rid[order], rel.key[order])
+            # Exchange: tuples cross groups to reach their owner (bounded
+            # above by the full relation; discrete pays the bus for the
+            # crossing part).
+            crossing = min(n_c, n - cut) + min(n - n_c, cut)
+            self._bus_delay(crossing * 8, timing)
+            n_c_pad = _round_up(max(n_c, 1), self.lcm)
+            n_g_pad = _round_up(max(n - n_c, 1), self.lcm)
+            rel_c = self.c.put_items(_pad_slice(srel, 0, n_c, n_c_pad,
+                                                self.BUILD_PAD_KEY))
+            rel_g = self.g.put_items(_pad_slice(srel, n_c, n, n_g_pad,
+                                                self.BUILD_PAD_KEY))
         fb_c = self.c.jit(("buildr", n_c_pad, num_buckets, "c"),
                           partial(ht.build_hash_table, num_buckets=num_buckets))
         fb_g = self.g.jit(("buildr", n_g_pad, num_buckets, "g"),
                           partial(ht.build_hash_table, num_buckets=num_buckets))
         part_c = fb_c(rel_c)
         part_g = fb_g(rel_g)
-        table = _concat_bucket_ranges(part_c,
-                                      jax.tree.map(jax.device_get, part_g),
-                                      own_c)
-        jax.block_until_ready(table.rids)
+        with self.tracer.span("build.collect"):
+            table = self._push(
+                self._pull(part_g, "shj.build.collect", "table"),
+                lambda t: _concat_bucket_ranges(part_c, t, own_c),
+                "shj.build.collect", "table")
+            jax.block_until_ready(table.rids)
         return table
 
     def _probe(self, rel: Relation, table: ht.HashTable, max_out: int,
@@ -477,7 +517,9 @@ class CoProcessor:
         if len(res) == 1:
             out = res[0]
             if self.discrete:
-                self._bus_delay(int(out.count) * 8, timing)
+                count = self.boundary.pull(out.count, site="shj.probe.count",
+                                           cause=None)
+                self._bus_delay(int(count) * 8, timing)
             if out.probe_rid.shape[0] > max_out:
                 # The per-group slack padded capacity past the caller's
                 # max_out; restore the contract (valid pairs are front-
@@ -486,14 +528,18 @@ class CoProcessor:
                                     out.build_rid[:max_out],
                                     jnp.minimum(out.count, max_out))
             return out
-        res_host = [jax.tree.map(jax.device_get, r) for r in res]
-        if self.discrete:
-            self._bus_delay(int(res_host[1].count) * 8, timing)
-        fcat = self.c.jit(("concat", tag,
-                           tuple(r.probe_rid.shape[0] for r in res_host),
-                           max_out),
-                          partial(concat_results, max_out=max_out))
-        return fcat([self.c.put_shared(r) for r in res_host])
+        with self.tracer.span("probe.collect"):
+            res_host = [self._pull(r, "shj.probe.collect", "result")
+                        for r in res]
+            if self.discrete:
+                self._bus_delay(int(res_host[1].count) * 8, timing)
+            fcat = self.c.jit(("concat", tag,
+                               tuple(r.probe_rid.shape[0] for r in res_host),
+                               max_out),
+                              partial(concat_results, max_out=max_out))
+            return fcat([self._push(r, self.c.put_shared,
+                                    "shj.probe.collect", "result")
+                         for r in res_host])
 
 
 def _phj_owned_join(rel_r: Relation, rel_s: Relation, *, total_bits: int,
@@ -556,9 +602,7 @@ class PhjCoProcessorMixin:
         shift = sum(sched[:start_pass])
 
         def collect() -> Relation:
-            pieces = [jax.tree.map(jax.device_get, r) for _, r in slices]
-            return Relation(jnp.concatenate([x.rid for x in pieces]),
-                            jnp.concatenate([x.key for x in pieces]))
+            return _collect_pieces(self, [r for _, r in slices], tag)
 
         for i in range(start_pass, len(sched)):
             if ctx is not None:
@@ -569,11 +613,17 @@ class PhjCoProcessorMixin:
                         ctx.note_partial(tag, collect(), i)
                     raise
             bits = sched[i]
-            slices = [(grp, grp.jit(
-                ("part_pass", tag, r.size, shift, bits, interpret),
-                partial(partition_pass, shift=shift, bits=bits,
-                        interpret=interpret, mesh=grp.mesh))(r))
-                for grp, r in slices]
+            done = []
+            for grp, r in slices:
+                with self.tracer.span("partition.side", side=tag,
+                                      group=grp.name, n=r.size,
+                                      pass_index=i):
+                    f = grp.jit(
+                        ("part_pass", tag, r.size, shift, bits, interpret),
+                        partial(partition_pass, shift=shift, bits=bits,
+                                interpret=interpret, mesh=grp.mesh))
+                    done.append((grp, f(r)))
+            slices = done
             shift += bits
         _maybe_fault("d2h")
         return collect()
@@ -672,19 +722,16 @@ class PhjCoProcessorMixin:
                 if self.discrete and 0 < cut < n:
                     self._bus_delay((n - cut) * 8, timing)
                 pieces = []
-                if cut > 0:
-                    f = self.c.jit(("phj_part", tag, cut, sched),
-                                   partial(part_fn, mesh=self.c.mesh))
-                    pieces.append(f(self.c.put_items(rel.take(0, cut))))
-                if cut < n:
-                    f = self.g.jit(("phj_part", tag, n - cut, sched),
-                                   partial(part_fn, mesh=self.g.mesh))
-                    pieces.append(f(self.g.put_items(rel.take(cut, n))))
+                for grp, lo, hi in ((self.c, 0, cut), (self.g, cut, n)):
+                    if hi <= lo:
+                        continue
+                    with self.tracer.span("partition.side", side=tag,
+                                          group=grp.name, n=hi - lo):
+                        f = grp.jit(("phj_part", tag, hi - lo, sched),
+                                    partial(part_fn, mesh=grp.mesh))
+                        pieces.append(f(grp.put_items(rel.take(lo, hi))))
                 _maybe_fault("d2h")
-                pieces = [jax.tree.map(jax.device_get, x) for x in pieces]
-                parts[tag] = Relation(
-                    jnp.concatenate([x.rid for x in pieces]),
-                    jnp.concatenate([x.key for x in pieces]))
+                parts[tag] = _collect_pieces(self, pieces, tag)
             if parts_out is not None:
                 for tag, _, _ in todo:
                     parts_out[tag] = parts[tag]
@@ -696,28 +743,40 @@ class PhjCoProcessorMixin:
             num_parts = 1 << total_bits
             own = self._cut(num_parts, join_ratio)
             results = []
+            # Each side's columns cross to the host once, on the first
+            # group's exchange; the other group slices the same host copy.
+            host_cols = {}
             for grp, sel in ((self.c, lambda pid: pid < own),
                              (self.g, lambda pid: pid >= own)):
                 if (own == 0 and grp is self.c) or (own == num_parts
                                                     and grp is self.g):
                     continue
                 sub = {}
-                for tag in ("R", "S"):
-                    rel = parts[tag]
-                    pid = radix_of(rel.key, shift=0, bits=total_bits)
-                    mask = np.asarray(sel(pid))
-                    idx = np.nonzero(mask)[0]
-                    m = _round_up(max(len(idx), 1), self.lcm)
-                    sent = (self.BUILD_PAD_KEY if tag == "R"
-                            else self.PROBE_PAD_KEY)
-                    rid = np.full(m, -1, np.int32)
-                    key = np.full(m, sent, np.int32)
-                    rid[:len(idx)] = np.asarray(rel.rid)[idx]
-                    key[:len(idx)] = np.asarray(rel.key)[idx]
-                    if self.discrete:
-                        self._bus_delay(len(idx) * 8 // 2, timing)
-                    sub[tag] = grp.put_items(Relation(jnp.asarray(rid),
-                                                      jnp.asarray(key)))
+                with self.tracer.span("exchange", group=grp.name):
+                    for tag in ("R", "S"):
+                        rel = parts[tag]
+                        pid = radix_of(rel.key, shift=0, bits=total_bits)
+                        mask = self._pull(sel(pid), "phj.exchange.mask",
+                                          f"{tag}.pid")
+                        idx = np.nonzero(mask)[0]
+                        m = _round_up(max(len(idx), 1), self.lcm)
+                        sent = (self.BUILD_PAD_KEY if tag == "R"
+                                else self.PROBE_PAD_KEY)
+                        if tag not in host_cols:
+                            host_cols[tag] = self._pull(
+                                rel, "phj.exchange.rows", f"{tag}.rid+key")
+                        cols = host_cols[tag]
+                        rid = np.full(m, -1, np.int32)
+                        key = np.full(m, sent, np.int32)
+                        rid[:len(idx)] = cols.rid[idx]
+                        key[:len(idx)] = cols.key[idx]
+                        if self.discrete:
+                            self._bus_delay(len(idx) * 8 // 2, timing)
+                        sub[tag] = self._push(
+                            Relation(rid, key),
+                            lambda r, grp=grp: grp.put_items(Relation(
+                                jnp.asarray(r.rid), jnp.asarray(r.key))),
+                            "phj.exchange.share", f"{tag}.rid+key")
                 # Full capacity per group: partition ownership is by radix
                 # value, so a skewed relation's hot partition (and all its
                 # matches) can land wholly on either side regardless of
@@ -728,15 +787,20 @@ class PhjCoProcessorMixin:
                                     shj_bits=shj_bits, max_out=mo))
                 results.append(f(sub["R"], sub["S"]))
             _maybe_fault("d2h")
-            results = [jax.tree.map(jax.device_get, r) for r in results]
-            if len(results) == 1:
-                out = results[0]
-            else:
-                fcat = self.c.jit(
-                    ("concat", tuple(r.probe_rid.shape[0] for r in results),
-                     max_out), partial(concat_results, max_out=max_out))
-                out = fcat([self.c.put_shared(r) for r in results])
-            jax.block_until_ready(out.probe_rid)
+            with self.tracer.span("join.collect"):
+                results = [self._pull(r, "phj.join.collect", "result")
+                           for r in results]
+                if len(results) == 1:
+                    out = results[0]
+                else:
+                    fcat = self.c.jit(
+                        ("concat",
+                         tuple(r.probe_rid.shape[0] for r in results),
+                         max_out), partial(concat_results, max_out=max_out))
+                    out = fcat([self._push(r, self.c.put_shared,
+                                           "phj.join.collect", "result")
+                                for r in results])
+                jax.block_until_ready(out.probe_rid)
         timing.wall_s = timing.phase_s["partition"] + timing.phase_s["join"]
         return out, timing
 
@@ -796,7 +860,6 @@ class PhjCoProcessorMixin:
         jax.block_until_ready(table.rids)
         t1 = time.perf_counter()
         timing.phase_s["build"] = t1 - t0
-        timing.merge_s = 0.0
 
         # probe chunks
         mo = max(64, _round_up(max_out // max(1, probe_rel.size // chunk), 8)
@@ -833,6 +896,16 @@ def _time_once(fn, *args) -> float:
     t0 = time.perf_counter()
     jax.block_until_ready(fn(*args))
     return time.perf_counter() - t0
+
+
+def _collect_pieces(cp: CoProcessor, pieces, tag: str) -> Relation:
+    """Per-group partition pieces pulled to the host and concatenated back
+    on the default device (one relation for the join phase)."""
+    host = [cp._pull(x, "phj.partition", f"{tag}.rid+key") for x in pieces]
+    return cp._push(
+        host, lambda h: Relation(jnp.concatenate([x.rid for x in h]),
+                                 jnp.concatenate([x.key for x in h])),
+        "phj.partition", f"{tag}.rid+key")
 
 
 def _pad_slice(rel: Relation, lo: int, hi: int, target: int,

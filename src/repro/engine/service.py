@@ -37,9 +37,10 @@ import numpy as np
 
 from repro.core.coprocess import CoProcessor, Timing
 from repro.core.hash_table import JoinResult, default_num_buckets
+from repro.core.relation import Relation
 from repro.obs import (CardinalityAudit, CostAudit, DriftDetector,
-                       FlightRecorder, MetricsRegistry, NULL_TRACER,
-                       SLOMonitor, Tracer, TransferLedger)
+                       FlightRecorder, HostBoundary, MetricsRegistry,
+                       NULL_TRACER, SLOMonitor, Tracer, TransferLedger)
 
 from .admission import (AdmissionController, Backpressure, QueueFull,
                         Tenant, TenantFairQueue)
@@ -303,12 +304,20 @@ class JoinQueryService:
         # cardinality pair lands in the cardinality audit.
         self.ledger = TransferLedger(self.metrics)
         self.cardinality = CardinalityAudit()
-        # A CoProcessor constructed standalone carries the no-op tracer;
-        # adopt it into this service's tracer so its phase spans land in
-        # the query lifecycle.  An explicitly-traced CoProcessor is left
-        # alone.
+        # Every host crossing the service and its pipelines make goes
+        # through ``boundary``: spanned, ledgered, counted as a sync.
+        self.boundary = HostBoundary(self.tracer, self.ledger, self.metrics)
+        # A CoProcessor constructed standalone carries the no-op tracer
+        # and no ledger or registry; adopt it into this service's so its
+        # phase spans land in the query lifecycle and its crossings and
+        # compiles in this service's ledger and registry.  A CoProcessor
+        # given its own is left alone.
         if getattr(self.cp, "tracer", None) is NULL_TRACER:
             self.cp.tracer = self.tracer
+        if getattr(self.cp, "ledger", None) is None:
+            self.cp.ledger = self.ledger
+        if getattr(self.cp, "metrics", None) is None:
+            self.cp.metrics = self.metrics
         # Deadline-aware multi-tenant admission: the controller prices
         # admit/degrade/shed decisions from planner estimates; the queue
         # serves tenants weighted-fair, EDF within each.  ``fifo`` mode is
@@ -476,20 +485,6 @@ class JoinQueryService:
     host_bytes_moved = property(
         lambda self: self._counter_total("host_bytes_moved"))
 
-    def note_host_bytes(self, nbytes: int, *, cause: str = "handoff",
-                        stage: str = "-", column: str = "-",
-                        direction: str = "d2h",
-                        tenant: str = "default") -> None:
-        """Attribute caller-side host-boundary traffic through the ledger.
-
-        The ledger increments ``host_bytes_moved`` for every intermediate
-        cause (``result`` bytes are attributed but excluded — final result
-        delivery was never counted as intermediate traffic), so the flat
-        counter stays a sum view over the ledger.
-        """
-        self.ledger.record(nbytes, cause=cause, stage=stage, column=column,
-                           direction=direction, tenant=tenant)
-
     def _fingerprint(self, rel, num_buckets: int, *,
                      stage: str = "-", column: str = "key",
                      tenant: str = "default") -> str:
@@ -508,14 +503,18 @@ class JoinQueryService:
         # this pulls both columns across the boundary — attributed under
         # the ledger's ``fingerprint`` cause (memo-missed pulls only; a
         # repeat of the same array objects hits the memo above).
-        pulled = sum(int(getattr(col, "nbytes", 0))
-                     for col in (rel.rid, rel.key)
-                     if not isinstance(col, np.ndarray))
-        fp = relation_fingerprint(rel, num_buckets)
-        if pulled:
-            self.ledger.record(pulled, cause="fingerprint", stage=stage,
-                               column=column, direction="d2h",
-                               tenant=tenant)
+        cols = {"rid": rel.rid, "key": rel.key}
+        dev = {k: v for k, v in cols.items()
+               if not isinstance(v, np.ndarray)}
+        with self.tracer.span("fingerprint", column=column,
+                              bytes=sum(int(getattr(v, "nbytes", 0))
+                                        for v in dev.values())):
+            if dev:
+                cols.update(self.boundary.pull(
+                    dev, site="fingerprint", cause="fingerprint",
+                    stage=stage, column=column, tenant=tenant))
+            fp = relation_fingerprint(Relation(cols["rid"], cols["key"]),
+                                      num_buckets)
         with self._lock:
             if len(self._fp_cache) > 256:
                 self._fp_cache.clear()

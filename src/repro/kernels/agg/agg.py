@@ -123,6 +123,9 @@ def _seg_agg_kernel(gid_ref, val_ref, cnt_ref, sum_ref, mn_ref, mx_ref, *,
     mx_ref[...] = mx
 
 
+# Stable name of the kernel in lowered modules and device traces.
+KERNEL_NAME = "segmented_aggregate"
+
 # Slots per grid block: one block's per-lane accumulators (count, min,
 # max and up to nine sum channels, 128 KiB each) stay well inside VMEM.
 SLOT_BLOCK = 256
@@ -171,6 +174,7 @@ def seg_agg_pallas(gid: jax.Array, val: jax.Array, *, num_slots: int,
                    jax.ShapeDtypeStruct((sum_rows, slots, lanes), jnp.int32),
                    acc, acc],
         interpret=interpret,
+        name=KERNEL_NAME,
     )(gid.reshape(rows, lanes), val.reshape(rows, lanes))
     sm = sm.sum(axis=-1)[:, :num_slots]
     return (cnt.sum(axis=-1)[:num_slots], sm[0] if wrap32 else sm,

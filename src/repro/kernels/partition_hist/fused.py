@@ -58,6 +58,10 @@ def _fused_kernel(keys_ref, pid_ref, hist_ref, *, shift: int, bits: int,
     hist_ref[...] = acc
 
 
+# Stable name of the kernel in lowered modules and device traces.
+KERNEL_NAME = "partition_hist_fused"
+
+
 @functools.partial(jax.jit,
                    static_argnames=("shift", "bits", "block_rows",
                                     "interpret"))
@@ -84,5 +88,6 @@ def partition_hist_fused_pallas(keys: jax.Array, *, shift: int, bits: int,
         out_shape=[jax.ShapeDtypeStruct((rows, LANES), jnp.int32),
                    jax.ShapeDtypeStruct((num_parts, LANES), jnp.int32)],
         interpret=interpret,
+        name=KERNEL_NAME,
     )(keys.reshape(rows, LANES))
     return pid.reshape(n), hist.sum(axis=1)

@@ -23,21 +23,35 @@ cause taxonomy:
     scalar-sink column pulls).  Someone always reads the answer; these
     bytes are attributed but — as everywhere in this repo since PR 5 —
     *not* counted as intermediate traffic.
+  * ``exchange``      — ``CoProcessor``'s own round trips between its
+    device groups through the host: partition pieces pulled and
+    re-uploaded, the PHJ ownership split (partition-id masks and both
+    columns pulled, each group's share uploaded), per-group results
+    pulled and re-uploaded for the concat, SHJ partial tables.
+  * ``base_upload``   — base-table columns (and scan indices) uploaded by
+    the executor's scans: the host-resident tables of ``queries.plan``.
 
 The flat ``host_bytes_moved`` counter is now a **sum view over the
 ledger**: :meth:`TransferLedger.record` increments it for every
-intermediate cause (everything except ``result``), so existing gates and
-tests keep their exact semantics while gaining attribution underneath.
+intermediate cause, so existing gates and tests keep their exact
+semantics while gaining attribution underneath.  ``exchange`` and
+``base_upload`` are deliberately *not* intermediate: ``host_bytes_moved``
+means pipeline hand-off traffic between stages (the fused path's
+invariant is that it reads 0), and neither is that — one is the engine's
+co-processing split inside a single join, the other the query's input.
+Both count in ``total_bytes`` and ``by_cause``.
 """
 from __future__ import annotations
 
 import threading
 from collections import deque
 
-CAUSES = ("fingerprint", "multicol_pack", "handoff", "result")
+CAUSES = ("fingerprint", "multicol_pack", "handoff", "result", "exchange",
+          "base_upload")
 #: Causes that count toward the service's ``host_bytes_moved`` counter.
 #: ``result`` is excluded — final result delivery has never been counted
-#: as intermediate traffic (see PR 5's fused-path invariant).
+#: as intermediate traffic (the fused path's invariant) — and so are
+#: ``exchange`` and ``base_upload`` (see the module docstring).
 INTERMEDIATE_CAUSES = ("fingerprint", "multicol_pack", "handoff")
 
 DIRECTIONS = ("h2d", "d2h")
@@ -89,7 +103,7 @@ class TransferLedger:
         if self._metrics is not None:    # registry lock is a leaf lock
             self._metrics.inc("host_transfer_bytes", n,
                               cause=cause, direction=direction)
-            if cause != "result":
+            if cause in INTERMEDIATE_CAUSES:
                 self._metrics.inc("host_bytes_moved", n)
 
     # -- readers -------------------------------------------------------------

@@ -19,6 +19,14 @@ wait is measured on the submitting thread but ends on a worker — are
 recorded with :meth:`Tracer.lane` and exported as Chrome *async* events,
 which carry no nesting constraint.
 
+One clock with the device: while a span is open it also holds a
+``jax.profiler.TraceAnnotation`` named ``repro.<span name>`` (no
+attributes in the name, so labels stay stable across queries).  Under a
+``jax.profiler`` session the program's spans land on the profiler's host
+timeline beside the device ops; with no session running the annotation
+is a no-op.  A span closing also records the compiling done inside it
+(``compile_s``, ``compiles``; see ``repro.obs.compiles``) when non-zero.
+
 Exports:
 
   * :meth:`Tracer.chrome_trace` / :meth:`Tracer.write_chrome_trace` —
@@ -32,12 +40,21 @@ defaults to it — pays nothing for the plumbing.
 """
 from __future__ import annotations
 
+import collections
 import contextlib
 import dataclasses
 import itertools
 import json
 import threading
 import time
+
+from jax.profiler import TraceAnnotation
+
+from . import compiles
+
+# Prefix of the profiler annotation each span holds: keeps program spans
+# apart from the benchmark harness's own ``bench.*`` annotations.
+ANNOTATION_PREFIX = "repro."
 
 # Attribute keys a child span inherits from its innermost open ancestor
 # on the same thread (unless it sets them itself).
@@ -83,8 +100,11 @@ class Tracer:
     """Thread-safe span recorder with an injectable clock.
 
     ``clock`` must be monotonic within one tracer (tests inject fake
-    clocks).  Finished spans are kept in a bounded ring; per-``q_key``
-    indexing serves the structured per-query trace on ``QueryOutcome``.
+    clocks).  Finished spans are kept in a bounded ring (the newest
+    ``max_spans``); per-``q_key``
+    indexing serves the structured per-query trace on ``QueryOutcome``
+    and goes on past the ring's cap, so a long-running service keeps
+    its per-query traces.
     """
 
     def __init__(self, clock=time.perf_counter, *, enabled: bool = True,
@@ -93,11 +113,14 @@ class Tracer:
         self.max_spans = int(max_spans)
         self._clock = clock
         self._lock = threading.Lock()
-        self._spans: list[SpanRecord] = []
+        self._spans: collections.deque[SpanRecord] = collections.deque(
+            maxlen=max(self.max_spans, 0))
         self._dropped = 0
         self._by_key: dict[int, list[SpanRecord]] = {}
         self._local = threading.local()
         self._key_seq = itertools.count(1)
+        if self.enabled:
+            compiles.install()
 
     # -- clocks and keys -----------------------------------------------------
     def now(self) -> float:
@@ -122,7 +145,9 @@ class Tracer:
         Yields the active span (``.set(**attrs)`` adds attributes
         mid-flight) or ``None`` when the tracer is disabled.  ``None``
         attribute values are dropped; ambient keys are inherited from the
-        innermost open ancestor on this thread.
+        innermost open ancestor on this thread.  The span holds a
+        ``repro.<name>`` profiler annotation while open, and closes with
+        its inclusive ``compile_s`` / ``compiles`` when they are non-zero.
         """
         if not self.enabled:
             yield None
@@ -134,15 +159,27 @@ class Tracer:
                 if k in parent and k not in attrs:
                     attrs[k] = parent[k]
         attrs = {k: v for k, v in attrs.items() if v is not None}
-        sp = _ActiveSpan(name, self.now(), attrs)
+        sp = _ActiveSpan(name, 0.0, attrs)
         stack.append(sp)
         try:
-            yield sp
+            # The interval is read inside the annotation, so a span
+            # measures the work it wraps and not its own instrument.
+            with TraceAnnotation(ANNOTATION_PREFIX + name):
+                c_s, c_n = compiles.thread_compiles()
+                sp.t0 = self.now()
+                try:
+                    yield sp
+                finally:
+                    t1 = self.now()
+                    d_s, d_n = compiles.thread_compiles()
+                    if d_n > c_n or d_s > c_s:
+                        sp.attrs["compile_s"] = d_s - c_s
+                        sp.attrs["compiles"] = d_n - c_n
+                    self._finish(SpanRecord(
+                        name, sp.t0, t1, threading.current_thread().name,
+                        sp.attrs))
         finally:
             stack.pop()
-            self._finish(SpanRecord(name, sp.t0, self.now(),
-                                    threading.current_thread().name,
-                                    sp.attrs))
 
     def lane(self, name: str, t0: float, t1: float, *,
              lane: str = "queue", **attrs) -> None:
@@ -151,6 +188,8 @@ class Tracer:
         Lane intervals start on one thread and end on another (queue
         wait), so they are exempt from per-thread nesting and become
         Chrome async (``b``/``e``) events rather than ``X`` slices.
+        They are not on the profiler's timeline: the profiler has no
+        retroactive event, so the Chrome export is the only view of them.
         """
         if not self.enabled:
             return
@@ -176,14 +215,13 @@ class Tracer:
 
     def _finish(self, rec: SpanRecord) -> None:
         with self._lock:
-            if len(self._spans) >= self.max_spans:
+            if len(self._spans) == self._spans.maxlen:
                 self._dropped += 1
-                return
             self._spans.append(rec)
             key = rec.attrs.get("q_key")
             if key is not None:
                 # The per-query index is bounded by wholesale reset: one
-                # query contributes ~10 spans, so the cap is generous.
+                # query contributes a few dozen spans; the cap is generous.
                 if len(self._by_key) > 8192:
                     self._by_key.clear()
                 self._by_key.setdefault(key, []).append(rec)
@@ -273,8 +311,7 @@ class NullTracer(Tracer):
     """
 
     def __init__(self):
-        super().__init__(enabled=True, max_spans=0)
-        self.enabled = False
+        super().__init__(enabled=False, max_spans=0)
 
 
 #: Shared no-op tracer instance (safe to share: it never records).

@@ -37,7 +37,6 @@ oracle (``groupby_ref``) reproduces either mode exactly.
 from __future__ import annotations
 
 import dataclasses
-import time
 from functools import partial
 
 import jax
@@ -317,10 +316,10 @@ def groupby_coprocessed(cp: CoProcessor, rel: Relation, values, *,
                           grp.put_items(jnp.asarray(vals)))]
             outs = [jax.tree.map(jax.device_get, o) for o in outs]
             if len(outs) == 2:
-                tm = time.perf_counter()
-                result = _merge_partials(_collect(outs[:1], wrap32=wrap32),
-                                         _collect(outs[1:], wrap32=wrap32))
-                timing.merge_s = time.perf_counter() - tm
+                with cp.tracer.span("merge"):
+                    result = _merge_partials(
+                        _collect(outs[:1], wrap32=wrap32),
+                        _collect(outs[1:], wrap32=wrap32))
             else:
                 result = _collect(outs, wrap32=wrap32)
     timing.wall_s = timing.phase_s["partition"] + timing.phase_s["agg"]

@@ -63,7 +63,7 @@ import numpy as np
 
 from repro.core.relation import IndexChain, Relation, next_pow2
 from repro.engine.service import GroupByQuery, JoinQuery, JoinQueryService
-from repro.obs import q_error
+from repro.obs import Tally, q_error
 
 from .optimize import JoinOrderOptimizer, PhysicalPlan
 from .plan import (NULL_VALUE, Query, agg_output_name, apply_aggregate,
@@ -87,10 +87,12 @@ class _ScanView:
     consumer's row selection so the filtered table is never materialized
     as a whole intermediate.  ``raw_chain``/``col_dev`` are the
     device-resident face of the same idea: the scan index becomes the
-    root link of a downstream ``IndexChain``.
+    root link of a downstream ``IndexChain``.  Its uploads (the scan index,
+    raw columns) cross ``boundary`` under the ledger's ``base_upload``.
     """
 
-    def __init__(self, table):
+    def __init__(self, table, boundary):
+        self._boundary = boundary
         self._name = table.name
         self._cols = table.columns          # raw, unfiltered
         self._idx = table.scan_indices()    # None = no filters
@@ -131,15 +133,19 @@ class _ScanView:
         """
         if self._chain is None:
             self._chain = (IndexChain() if self._idx is None else
-                           IndexChain((jnp.asarray(self._idx,
-                                                   dtype=jnp.int32),)))
+                           IndexChain((self._boundary.push(
+                               self._idx.astype(np.int32, copy=False),
+                               jnp.asarray, site="scan",
+                               cause="base_upload", stage=self._name,
+                               column="scan_index"),)))
         return self._raw(q), self._chain, None
 
     def col_dev(self, q: str) -> jax.Array:
         """One filtered column as a device array (memoized)."""
         if q not in self._dev_memo:
             raw, chain, _ = self.raw_chain(q)
-            self._dev_memo[q] = chain.gather(raw)
+            self._dev_memo[q] = chain.gather(
+                _upload_raw(self._boundary, raw, q))
         return self._dev_memo[q]
 
     def _rows_token(self) -> str:
@@ -196,6 +202,12 @@ class _ScanView:
         self._rows_tok = None
 
 
+def _upload_raw(boundary, raw, q: str) -> jax.Array:
+    """A raw host column of a base table, uploaded for a device gather."""
+    return boundary.push(raw, jnp.asarray, site="scan", cause="base_upload",
+                         stage=q.partition(".")[0], column=q)
+
+
 @functools.partial(jax.jit, static_argnames=("kind",))
 def _match_stats_jit(bkey: jax.Array, pkey: jax.Array, kind: str):
     """Exact stage output cardinality, computed on device (two
@@ -241,8 +253,9 @@ class StageView:
     """
 
     def __init__(self, kind: str, psrc, bsrc, probe_rid, build_rid,
-                 count: int, token: str | None = None):
+                 count: int, boundary, token: str | None = None):
         self.kind = kind
+        self._boundary = boundary       # raw base columns upload through it
         self._psrc, self._bsrc = psrc, bsrc
         self._pr = probe_rid
         self._br = build_rid
@@ -320,7 +333,7 @@ class StageView:
         flattened-chain gather, NULL-masked when an outer edge applies."""
         if q not in self._col_memo:
             raw, chain, mask = self.raw_chain(q)
-            col = chain.gather(raw)
+            col = chain.gather(_upload_raw(self._boundary, raw, q))
             if mask is not None:
                 col = _null_fill(col, mask)
             self._col_memo[q] = col
@@ -390,9 +403,10 @@ def _src_take(src, rows: np.ndarray) -> dict:
     return {q: v[rows] for q, v in src.items()}
 
 
-def _as_relation(col: np.ndarray, fill_key: int) -> Relation:
+def _as_relation(col: np.ndarray, fill_key: int, upload) -> Relation:
     """A core Relation over a host column, rid = row index (gather
-    convention) — the host-materialize path's H2D upload.
+    convention) — the host-materialize path's H2D upload, made by
+    ``upload((rid, key))``.
 
     The fingerprint hint is a content hash computed from the *host* copy
     before the upload, so the engine's cache keying never pulls the
@@ -411,8 +425,8 @@ def _as_relation(col: np.ndarray, fill_key: int) -> Relation:
         rid = np.concatenate([rid, np.full(pad, -1, np.int32)])
     h = hashlib.sha1(col.tobytes())
     h.update(rid.tobytes())
-    return Relation(jnp.asarray(rid), jnp.asarray(col),
-                    fp_hint=f"host:{h.hexdigest()}")
+    rid, col = upload((rid, col))
+    return Relation(rid, col, fp_hint=f"host:{h.hexdigest()}")
 
 
 def _as_relation_dev(col: jax.Array, fill_key: int,
@@ -434,11 +448,12 @@ def _as_relation_dev(col: jax.Array, fill_key: int,
     return Relation(rid, col, fp_hint=hint)
 
 
-def _check_keys_nonneg(*keys) -> None:
+def _check_keys_nonneg(boundary, *keys) -> None:
     """Negative-key validation for the fused path: only O(1) scalars
     (the mins) cross the host boundary."""
     for k in keys:
-        if k.shape[0] and int(k.min()) < 0:
+        if k.shape[0] and int(boundary.pull(k.min(), site="stage.key_min",
+                                            cause=None)) < 0:
             raise ValueError(
                 "negative join-key values are unsupported: they collide "
                 "with the executor's fill keys and the engine's pad "
@@ -634,7 +649,8 @@ class PipelineExecutor:
                 tenant=tenant, est_s=physical.est_total_s,
                 deadline_s=deadline_s, query_id=next(self._qid),
                 degraded_est_s=self._degraded_total_s(physical))
-            base = {name: _ScanView(t) for name, t in query.tables.items()}
+            base = {name: _ScanView(t, self.service.boundary)
+                    for name, t in query.tables.items()}
             # Residual (cycle-edge) filters on base tables apply at scan
             # time; the rest are grouped by the stage whose output they
             # filter.
@@ -656,7 +672,7 @@ class PipelineExecutor:
             inter: dict[int, object] = {}  # stage id -> cols | StageView
             depth: dict[int, int] = {}
             handles: dict[int, object] = {}
-            handoff_bytes: dict[int, int] = {}  # host-path H2D per stage
+            handoff_bytes: dict[int, Tally] = {}  # host-path H2D per stage
             fused = self.handoff == "device"
             # Adaptive mid-pipeline re-optimization needs the frontier-wave
             # schedule (observe a wave, then admit the next); it applies on
@@ -851,10 +867,9 @@ class PipelineExecutor:
             return cols.n
         q = query.aggregate[1]
         if isinstance(cols, StageView):
-            arr = np.asarray(cols.col_dev(q))
-            self.service.note_host_bytes(
-                arr.nbytes, cause="result", stage="sink", column=q,
-                direction="d2h")
+            arr = self.service.boundary.pull(
+                cols.col_dev(q), site="sink.column", cause="result",
+                stage="sink", column=q)
             return apply_aggregate({q: arr}, query.aggregate)
         return apply_aggregate({q: cols.col(q)}, query.aggregate)
 
@@ -872,7 +887,7 @@ class PipelineExecutor:
         as hand-off traffic honestly.
         """
         aggregate = query.aggregate or ("count",)
-        moved = 0
+        moved = Tally() if count_handoff else None
         is_view = isinstance(cols, (StageView, _ScanView))
         if is_view and len(query.group_by) == 1:
             q = query.group_by[0]
@@ -897,16 +912,14 @@ class PipelineExecutor:
                 need = set(query.group_by)
                 if aggregate[0] != "count":
                     need.add(aggregate[1])
-                host_cols = {q: np.asarray(cols.col_dev(q))
-                             if isinstance(cols, StageView)
-                             else cols.col(q) for q in need}
-                if isinstance(cols, StageView) and count_handoff:
-                    pulled = sum(v.nbytes for v in host_cols.values())
-                    moved += pulled
-                    self.service.note_host_bytes(
-                        pulled, cause="multicol_pack",
-                        stage="groupby-sink", column="+".join(sorted(need)),
-                        direction="d2h")
+                if isinstance(cols, StageView):
+                    host_cols = self.service.boundary.pull(
+                        {q: cols.col_dev(q) for q in need},
+                        site="groupby.pack", cause="multicol_pack",
+                        stage="groupby-sink",
+                        column="+".join(sorted(need)), tally=moved)
+                else:
+                    host_cols = {q: cols.col(q) for q in need}
                 cols = host_cols
             keys, decode = self._encode_group_keys(cols, query.group_by)
             n = keys.shape[0]
@@ -920,20 +933,23 @@ class PipelineExecutor:
                 keys = np.concatenate([keys,
                                        np.full(pad, -4, np.int32)])
                 rid = np.concatenate([rid, np.full(pad, -1, np.int32)])
+            # Host hand-off into the sink: keys + rid + values H2D (the
+            # values cross inside the group-by, counted here with the
+            # rest).  Packed multi-column keys sourced from a device view
+            # are packing traffic (``multicol_pack``), not a hand-off —
+            # the fused path's ``handoff`` cause stays zero; with no join
+            # stage before the sink, the keys are a base table's.
             if count_handoff:
-                # Host hand-off into the sink: keys + rid + values H2D.
-                # Packed multi-column keys sourced from a device view are
-                # packing traffic (``multicol_pack``), not a hand-off —
-                # the fused path's ``handoff`` cause stays zero.
-                upload = keys.nbytes + rid.nbytes + values.nbytes
-                moved += upload
-                self.service.note_host_bytes(
-                    upload,
-                    cause="multicol_pack" if is_view else "handoff",
-                    stage="groupby-sink", column="keys+rid+values",
-                    direction="h2d")
-            rel = Relation(jnp.asarray(rid),
-                           jnp.asarray(keys, dtype=jnp.int32))
+                cause = "multicol_pack" if is_view else "handoff"
+            else:
+                cause = "base_upload"
+            rel, values = self.service.boundary.push(
+                (rid, keys, values),
+                lambda t: (Relation(jnp.asarray(t[0]),
+                                    jnp.asarray(t[1], dtype=jnp.int32)),
+                           t[2]),
+                site="groupby.sink", cause=cause, stage="groupby-sink",
+                column="keys+rid+values", tally=moved)
         gq = GroupByQuery(keys=rel, values=values, tag="groupby-sink",
                           query_id=next(self._qid), wrap32=query.wrap32,
                           tenant=tenant, deadline_at=deadline_at,
@@ -944,7 +960,8 @@ class PipelineExecutor:
             # Pre-admitted: the pipeline-root decision already covered the
             # sink; re-deciding here could shed it after its stages ran.
             outcome = self.service.submit(gq, preadmitted=True)()
-        outcome.host_bytes_moved += moved
+        if moved is not None:
+            outcome.host_bytes_moved += moved.bytes
         res = outcome.result
         out = decode(res.keys)
         name = agg_output_name(aggregate)
@@ -1020,8 +1037,11 @@ class PipelineExecutor:
             psrc = self._input(stage.probe_input, base, inter)
             bkey = bsrc.col_dev(stage.build_col)
             pkey = psrc.col_dev(stage.probe_col)
-            _check_keys_nonneg(bkey, pkey)
-            matches = int(_match_stats_jit(bkey, pkey, stage.kind))
+            boundary = self.service.boundary
+            _check_keys_nonneg(boundary, bkey, pkey)
+            matches = int(boundary.pull(
+                _match_stats_jit(bkey, pkey, stage.kind),
+                site="stage.matches", cause=None))
             return JoinQuery(
                 build=_as_relation_dev(
                     bkey, BUILD_FILL_KEY,
@@ -1048,14 +1068,17 @@ class PipelineExecutor:
                                               stage=stage.stage_id):
                     bsrc = self._input(stage.build_input, base, inter)
                     psrc = self._input(stage.probe_input, base, inter)
-                    c = int(outcome.result.count)
+                    boundary = self.service.boundary
+                    c = int(boundary.pull(outcome.result.count,
+                                          site="stage.count", cause=None))
                     token = self._stage_token(stage, bsrc, psrc,
                                               outcome.plan, c)
                     view = StageView(
                         stage.kind, psrc, bsrc,
                         outcome.result.probe_rid[:c],
                         None if stage.kind in ("semi", "anti")
-                        else outcome.result.build_rid[:c], c, token=token)
+                        else outcome.result.build_rid[:c], c, boundary,
+                        token=token)
                     for lq, rq in residuals:
                         view.apply_residual(lq, rq)
                 inter[stage.stage_id] = view
@@ -1096,22 +1119,24 @@ class PipelineExecutor:
             bkey = _src_col(bsrc, stage.build_col)
             pkey = _src_col(psrc, stage.probe_col)
             matches = _match_count(bkey, pkey, stage.kind)
-            # H2D re-upload of intermediate-derived inputs: rid + key per
-            # side whose source is a host-materialized stage output.
-            moved = sum(
-                2 * 4 * max(k.shape[0], MIN_STAGE_ROWS)
-                for src, k in ((bsrc, bkey), (psrc, pkey))
-                if isinstance(src, dict))
-            if moved:
-                handoff_bytes[stage.stage_id] = \
-                    handoff_bytes.get(stage.stage_id, 0) + moved
-                self.service.note_host_bytes(
-                    moved, cause="handoff",
+
+            def upload(src):
+                # H2D re-upload of a side whose source is a
+                # host-materialized stage output is hand-off traffic,
+                # tallied for the stage's outcome; a base-table side is
+                # the scan's upload.
+                handoff = isinstance(src, dict)
+                return functools.partial(
+                    self.service.boundary.push,
+                    place=lambda t: tuple(map(jnp.asarray, t)),
+                    site="stage.input",
+                    cause="handoff" if handoff else "base_upload",
                     stage=f"stage{stage.stage_id}", column="rid+key",
-                    direction="h2d")
+                    tally=(handoff_bytes.setdefault(stage.stage_id, Tally())
+                           if handoff else None))
             return JoinQuery(
-                build=_as_relation(bkey, BUILD_FILL_KEY),
-                probe=_as_relation(pkey, PROBE_FILL_KEY),
+                build=_as_relation(bkey, BUILD_FILL_KEY, upload(bsrc)),
+                probe=_as_relation(pkey, PROBE_FILL_KEY, upload(psrc)),
                 tag=f"stage{stage.stage_id}:{stage.join}",
                 max_out=self._stage_capacity(matches),
                 query_id=next(self._qid), kind=stage.kind)
@@ -1128,15 +1153,24 @@ class PipelineExecutor:
                                               stage=stage.stage_id):
                     bsrc = self._input(stage.build_input, base, inter)
                     psrc = self._input(stage.probe_input, base, inter)
-                    c = int(outcome.result.count)
-                    pr = np.asarray(outcome.result.probe_rid[:c])
-                    moved = pr.nbytes              # D2H: match indices
+                    boundary = self.service.boundary
+
+                    moved = Tally()                # D2H: match indices
+
+                    def pull(x):
+                        return boundary.pull(
+                            x, site="stage.gather", cause="handoff",
+                            stage=f"stage{stage.stage_id}",
+                            column="match_rids", tenant=outcome.tenant,
+                            tally=moved)
+                    c = int(boundary.pull(outcome.result.count,
+                                          site="stage.count", cause=None))
+                    pr = pull(outcome.result.probe_rid[:c])
                     cols = _src_take(psrc, pr)
                     if stage.kind in ("semi", "anti"):
                         pass  # filter table consumed: probe columns only
                     elif stage.kind == "left_outer":
-                        br = np.asarray(outcome.result.build_rid[:c])
-                        moved += br.nbytes
+                        br = pull(outcome.result.build_rid[:c])
                         # Unmatched rows carry NULL_VALUE on the build
                         # side.  An empty build side (filtered to nothing)
                         # has no rows to gather at all — everything is
@@ -1152,18 +1186,14 @@ class PipelineExecutor:
                                 cols[q] = np.where(matched, v,
                                                    v.dtype.type(NULL_VALUE))
                     else:
-                        br = np.asarray(outcome.result.build_rid[:c])
-                        moved += br.nbytes
+                        br = pull(outcome.result.build_rid[:c])
                         cols.update(_src_take(bsrc, br))
                 for lq, rq in residuals:
                     cols = _apply_residual(cols, lq, rq)
                 inter[stage.stage_id] = cols
-                self.service.note_host_bytes(
-                    moved, cause="handoff",
-                    stage=f"stage{stage.stage_id}", column="match_rids",
-                    direction="d2h")
-                outcome.host_bytes_moved = moved + \
-                    (handoff_bytes or {}).get(stage.stage_id, 0)
+                uploaded = (handoff_bytes or {}).get(stage.stage_id)
+                outcome.host_bytes_moved = moved.bytes + (
+                    uploaded.bytes if uploaded is not None else 0)
                 self.service.cardinality.record(
                     stage_type=stage.kind, est_rows=stage.est_out,
                     observed_rows=c, depth=depth, tenant=outcome.tenant,

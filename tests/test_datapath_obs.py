@@ -101,7 +101,8 @@ def test_ledger_records_and_sums():
     led.record(-5, cause="handoff")
     by_cause = led.by_cause()
     assert by_cause == {"fingerprint": 30, "multicol_pack": 70,
-                        "handoff": 150, "result": 999}
+                        "handoff": 150, "result": 999, "exchange": 0,
+                        "base_upload": 0}
     # The flat counter is a sum view over the intermediate causes only.
     assert led.total() == 250
     assert led.total(intermediate_only=False) == 1249
@@ -378,3 +379,107 @@ def test_service_accepts_tenant_cache_budget():
         assert svc.cache.tenant_budget_bytes == 64 << 10
     finally:
         svc.close()
+
+
+# ---------------------------------------------------------------------------
+# Host crossings: CoProcessor's exchange, the executor's base uploads.
+# ---------------------------------------------------------------------------
+
+def _round_up(n, k):
+    return -(-n // k) * k
+
+
+def test_phj_exchange_bytes_match_shape_reckoning():
+    """Every crossing of a CPU PHJ, reckoned from its shapes: partition
+    pieces down and back up, the ownership split (a bool pid mask per
+    side per group and both int32 columns per side once down, each
+    group's share up),
+    and both groups' results down and back up for the concat."""
+    from repro.core import CoProcessor, uniform_relation
+    from repro.core.relation import radix_of
+    metrics = MetricsRegistry()
+    cp = CoProcessor()
+    cp.ledger, cp.metrics = TransferLedger(metrics), metrics
+    n_r, n_s, max_out, sched = 3072, 2048, 9000, (3, 2)
+    b = uniform_relation(n_r, seed=41)
+    s = uniform_relation(n_s, key_range=n_r, seed=42)
+    cp.phj(b, s, schedule=sched, shj_bits=2, max_out=max_out,
+           partition_ratio=0.5, join_ratio=0.5)
+    bits = sum(sched)
+    own = cp._cut(1 << bits, 0.5)
+    groups = 2                     # both own partitions at join_ratio 0.5
+    share_up = 0
+    for rel in (b, s):
+        pid = np.asarray(radix_of(rel.key, shift=0, bits=bits))
+        for mask in (pid < own, pid >= own):
+            share_up += 8 * _round_up(max(int(mask.sum()), 1), cp.lcm)
+    mo = _round_up(max_out, 8) + 64
+    result = 2 * 4 * mo + 4        # probe_rid + build_rid + count
+    want = {
+        "phj.partition": {"d2h": 8 * (n_r + n_s), "h2d": 8 * (n_r + n_s)},
+        "phj.exchange.mask": {"d2h": groups * (n_r + n_s)},
+        "phj.exchange.rows": {"d2h": 8 * (n_r + n_s)},
+        "phj.exchange.share": {"h2d": share_up},
+        "phj.join.collect": {"d2h": groups * result, "h2d": groups * result},
+    }
+    got: dict = {}
+    for e in cp.ledger.entries():
+        assert e["cause"] == "exchange"
+        d = got.setdefault(e["stage"], {})
+        d[e["direction"]] = d.get(e["direction"], 0) + e["nbytes"]
+    assert got == want
+    assert cp.ledger.by_cause()["exchange"] == sum(
+        v for d in want.values() for v in d.values())
+    # Not intermediate traffic: the flat counter never moved.
+    assert metrics.counter_value("host_bytes_moved") == 0
+
+
+def _two_table_query(n_fact=4096, n_dim=256, seed=5):
+    rng = np.random.default_rng(seed)
+    fact = Table("F", {"fk": rng.integers(0, n_dim, n_fact),
+                       "v": rng.integers(0, 100, n_fact)})
+    dim = Table("D", {"id": np.arange(n_dim), "a": rng.integers(0, 9, n_dim)})
+    return Query(tables={"F": fact, "D": dim},
+                 joins=(Join("F", "fk", "D", "id"),), aggregate=("count",))
+
+
+@pytest.mark.parametrize("handoff", ["device", "host"])
+def test_base_upload_equals_scanned_column_bytes(handoff):
+    """Unfiltered scans: the fused path uploads each join key column once
+    (4 bytes a row, no scan index); the host path uploads rid + key per
+    side (8 bytes a row).  None of it is intermediate traffic."""
+    n_fact, n_dim = 4096, 256
+    query = _two_table_query(n_fact, n_dim)
+    svc = make_service()
+    opt = JoinOrderOptimizer(svc.planner, handoff=handoff)
+    with PipelineExecutor(service=svc, optimizer=opt,
+                          handoff=handoff) as ex:
+        res = ex.run(query)
+        assert res.aggregate == reference_execute(query)[1]
+        per_row = 4 if handoff == "device" else 8
+        assert svc.ledger.by_cause()["base_upload"] == \
+            per_row * (n_fact + n_dim)
+        assert svc.stats()["host_bytes_moved"] == res.host_bytes_moved
+
+
+@pytest.mark.parametrize("case", ["fused_star", "multicol_groupby"])
+def test_new_causes_leave_host_bytes_moved_unchanged(case):
+    """``exchange`` and ``base_upload`` are ledgered but not intermediate:
+    the fused path's ``host_bytes_moved`` reads what it always read."""
+    if case == "fused_star":
+        query = make_star_query(4096, [256, 128], seed=3, aggregate=None)
+    else:
+        query = make_star_query(4096, [128, 64], seed=5,
+                                aggregate=("count",),
+                                group_by=("D0.a", "D1.a"))
+    svc = make_service()
+    with PipelineExecutor(service=svc) as ex:
+        res = ex.run(query)
+        by_cause = svc.ledger.by_cause()
+        assert by_cause["base_upload"] > 0
+        assert svc.stats()["host_bytes_moved"] == res.host_bytes_moved == \
+            sum(by_cause[c] for c in INTERMEDIATE_CAUSES)
+        if case == "fused_star":
+            assert res.host_bytes_moved == 0
+        else:
+            assert res.host_bytes_moved == by_cause["multicol_pack"] > 0

@@ -5,6 +5,8 @@ Tracer tests run on an injected fake clock — fully deterministic; the
 service-level tests drive real joins through ``JoinQueryService`` and
 validate the trace/metrics/audit the execution left behind.
 """
+import importlib.util
+import os
 import threading
 
 import pytest
@@ -112,6 +114,45 @@ def test_tracer_bounds_span_count():
         with tr.span(f"s{i}"):
             pass
     assert len(tr.spans()) == 3
+
+
+def test_tracer_past_cap_keeps_newest_and_per_query_traces():
+    """Past ``max_spans`` the ring evicts its oldest spans, and per-query
+    traces go on being indexed: a long-running service keeps them."""
+    tr = Tracer(clock=FakeClock(), max_spans=4)
+    for key in range(1, 6):
+        with tr.span("query", q_key=key):
+            with tr.span("plan"):
+                pass
+    assert [(s.name, s.attrs["q_key"]) for s in tr.spans()] == [
+        ("plan", 4), ("query", 4), ("plan", 5), ("query", 5)]
+    for key in range(1, 6):
+        assert [s["name"] for s in tr.spans_for(key)] == ["plan", "query"]
+
+
+def test_span_interval_excludes_its_annotation(monkeypatch):
+    """A span's interval is read inside its profiler annotation: entering
+    and leaving the annotation fall outside ``t0``..``t1``."""
+    import repro.obs.trace as trace_mod
+    clock = FakeClock()
+
+    class Costly:
+        def __init__(self, name):
+            pass
+
+        def __enter__(self):
+            clock.t += 5.0
+
+        def __exit__(self, *exc):
+            clock.t += 7.0
+            return False
+
+    monkeypatch.setattr(trace_mod, "TraceAnnotation", Costly)
+    tr = Tracer(clock=clock)
+    with tr.span("plan"):
+        clock.t += 1.0
+    (rec,) = tr.spans()
+    assert rec.t1 - rec.t0 == pytest.approx(1.0)
 
 
 # ---------------------------------------------------------------------------
@@ -358,3 +399,166 @@ def test_audit_est_matches_executed_degraded_plan(cp):
         assert rec["tenant"] == "t"
     # The measured side is the real executed phase time.
     assert {r["phase"] for r in recs} <= set(out.timing.phase_s)
+
+
+# ---------------------------------------------------------------------------
+# One clock with the device trace; compile attribution; host syncs.
+# ---------------------------------------------------------------------------
+BENCH_DIR = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "bench")
+
+
+def _bench_module(rel_path: str, name: str):
+    spec = importlib.util.spec_from_file_location(
+        name, os.path.join(BENCH_DIR, rel_path))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def _phj_planner():
+    # Tiny cache + harsh random-access penalty: PHJ wins at 4k tuples.
+    return QueryPlanner(delta=0.25, cache_bytes=1 << 10, rand_penalty=8.0,
+                        phj_overhead_s=0.0)
+
+
+def _phj_query(n_build=4096, n_probe=4096, qid=1):
+    b = uniform_relation(n_build, seed=31)
+    s = uniform_relation(n_probe, key_range=n_build, seed=32)
+    return JoinQuery(build=b, probe=s, query_id=qid,
+                     max_out=4 * n_probe + 1024)
+
+
+def test_span_holds_profiler_annotation_null_tracer_none(monkeypatch):
+    import repro.obs.trace as trace_mod
+    entered = []
+
+    class Recorder:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            entered.append(self.name)
+
+        def __exit__(self, *exc):
+            return False
+
+    monkeypatch.setattr(trace_mod, "TraceAnnotation", Recorder)
+    with NULL_TRACER.span("query"), Tracer(enabled=False).span("plan"):
+        pass
+    assert entered == []
+    with Tracer(clock=FakeClock()).span("exchange", group="C") as sp:
+        sp.set(bytes=8)
+    assert entered == ["repro.exchange"]      # no attributes in the name
+
+
+def test_profiler_trace_holds_program_spans_in_window(tmp_path):
+    """A real profiler trace of one PHJ service join on the CPU: the
+    program's spans share the profiler's clock with the harness's
+    ``bench.window``, under stable ``repro.*`` names."""
+    import jax
+    reduce = _bench_module("trace_reduce.py", "obs_test_trace_reduce")
+    svc = JoinQueryService(cp=CoProcessor(), planner=_phj_planner(),
+                           num_workers=0)
+    q = _phj_query()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with jax.profiler.TraceAnnotation("bench.window"):
+            out = svc.execute(q)
+            jax.block_until_ready(out.result)
+    finally:
+        jax.profiler.stop_trace()
+    svc.close()
+    assert out.plan.algorithm == "phj"
+    planes = reduce.load_xplane(reduce.find_xplane(str(tmp_path)))
+    lo, hi = reduce.window_of(planes)
+    inside = {n for (n, s, d, _) in reduce.host_events(planes)
+              if n.startswith("repro.") and lo <= s and s + d <= hi}
+    assert {"repro.query", "repro.partition.side",
+            "repro.exchange"} <= inside
+    assert {"repro.launch", "repro.d2h", "repro.h2d",
+            "repro.join.collect"} <= inside
+
+
+def test_first_launch_carries_compiles_repeat_does_not():
+    svc = JoinQueryService(cp=CoProcessor(), planner=_phj_planner(),
+                           num_workers=0)
+    q = _phj_query(4000, 3000)
+
+    def launches(outcome):
+        return [s for s in outcome.trace if s["name"] == "launch"
+                and s["attrs"]["program"] == "phj_join"]
+
+    first = svc.execute(q)
+    assert first.plan.algorithm == "phj"
+    assert launches(first)
+    assert all(s["attrs"]["compile_s"] > 0 and s["attrs"]["compiles"] >= 1
+               for s in launches(first))
+    again = svc.execute(JoinQuery(build=q.build, probe=q.probe, query_id=2,
+                                  max_out=q.max_out))
+    assert launches(again)
+    assert not any("compile_s" in s["attrs"] or "compiles" in s["attrs"]
+                   for s in launches(again))
+    # The launch span is the one per-program record: no registry copy.
+    assert not any(k.startswith(("compiles", "compile_s"))
+                   for k in svc.metrics.snapshot())
+    svc.close()
+
+
+def test_phj_phase_keys_stay_partition_and_join():
+    """The new spans are tracer-only: ``coprocess_ms`` sums ``phase_s``,
+    whose PHJ keys stay exactly these two."""
+    cp = CoProcessor(tracer=Tracer())
+    q = _phj_query(2048, 2048)
+    _, timing = cp.phj(q.build, q.probe, schedule=(3, 2), shj_bits=2,
+                       max_out=q.max_out, partition_ratio=0.5,
+                       join_ratio=0.5)
+    assert set(timing.phase_s) == {"partition", "join"}
+    assert not hasattr(timing, "merge_s")
+    names = {s.name for s in cp.tracer.spans()}
+    assert {"partition.side", "exchange", "launch", "join.collect", "d2h",
+            "h2d"} <= names
+
+
+def test_host_syncs_count_each_pull_once():
+    metrics = MetricsRegistry()
+    cp = CoProcessor(tracer=Tracer())
+    cp.metrics = metrics
+    q = _phj_query(2048, 2048)
+    cp.phj(q.build, q.probe, schedule=(3, 2), shj_bits=2, max_out=q.max_out,
+           partition_ratio=0.5, join_ratio=0.5)
+    pulls: dict = {}
+    for s in cp.tracer.spans():
+        if s.name == "d2h":
+            pulls[s.attrs["site"]] = pulls.get(s.attrs["site"], 0) + 1
+    # Two sides x two groups for partition pieces and pid masks; each
+    # side's rows once (both groups slice one host copy); one result per
+    # group.
+    assert pulls == {"phj.partition": 4, "phj.exchange.mask": 4,
+                     "phj.exchange.rows": 2, "phj.join.collect": 2}
+    snap = metrics.snapshot()
+    assert {site: snap[f"host_syncs{{site={site}}}"] for site in pulls} \
+        == pulls
+    assert snap["host_syncs"] == sum(pulls.values())
+
+
+def test_standalone_coprocessor_records_nothing():
+    cp = CoProcessor()
+    q = _phj_query(2048, 2048)
+    cp.phj(q.build, q.probe, schedule=(3, 2), shj_bits=2, max_out=q.max_out,
+           partition_ratio=0.5, join_ratio=0.5)
+    assert cp.tracer is NULL_TRACER and NULL_TRACER.spans() == []
+    assert cp.ledger is None and cp.metrics is None
+
+
+@pytest.mark.parametrize("completed,compile_s,want", [
+    (2, 2.5, 1250.0), (1, 0.0, 0.0), (0, 3.0, None)])
+def test_compile_ms_metric_reads_window_compile_seconds(completed,
+                                                        compile_s, want):
+    from types import SimpleNamespace
+    mod = _bench_module("metrics/compile_ms.py", "obs_test_compile_ms")
+    r = SimpleNamespace(completed=completed,
+                        compiles=SimpleNamespace(compile_s=compile_s))
+    assert mod.UNIT == "ms"
+    got = mod.read(r)
+    assert got == (None if want is None else pytest.approx(want))

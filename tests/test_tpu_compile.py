@@ -96,3 +96,30 @@ def test_segmented_aggregate_compiles_sharded(g_group):
     _assert_kernel(jax.jit(partial(
         segmented_aggregate, num_slots=1 << 14, use_pallas=True,
         mesh=g_group)).lower(_i32(n, sh), _i32(n, sh)))
+
+
+@pytest.mark.parametrize("kernel", ["partition_hist_fused",
+                                    "segmented_aggregate"])
+def test_kernel_keeps_its_stable_name(kernel, one_chip):
+    """The ``name`` each main-path ``pallas_call`` passes reaches the
+    lowered module (Mosaic's ``kernel_name``) and names the compiled
+    custom call, whose HLO instruction name is the op name a device
+    trace shows."""
+    import re
+    from repro.kernels.agg import agg
+    from repro.kernels.partition_hist import fused
+    if kernel == "partition_hist_fused":
+        assert fused.KERNEL_NAME == kernel
+        n = 1 << 17
+        lowered = jax.jit(partial(fused_partition_pass, shift=0, bits=8,
+                                  use_pallas=True)).lower(
+            Relation(_i32(n, one_chip), _i32(n, one_chip)))
+    else:
+        assert agg.KERNEL_NAME == kernel
+        n = 1 << 20
+        lowered = jax.jit(partial(segmented_aggregate, num_slots=1024,
+                                  use_pallas=True)).lower(
+            _i32(n, one_chip), _i32(n, one_chip))
+    assert f'kernel_name = "{kernel}"' in lowered.as_text()
+    assert re.search(rf"%{kernel}(\.\d+)? = .*custom-call",
+                     lowered.compile().as_text())
