@@ -46,6 +46,35 @@ def _round_up(n: int, k: int) -> int:
     return ((n + k - 1) // k) * k
 
 
+# Share capacities are whole multiples of this many rows per device.
+_SHARE_QUANTUM = 1024
+
+
+def _share_capacity(n: int, own: int, num_parts: int, rows: int,
+                    lcm: int) -> tuple[int, int]:
+    """(capacity, rung) of a group's share of one partitioned side.
+
+    The share's shape is a jit key, so it is set before the data: the
+    base rung is the expected share of the side's ``n`` rows for a group
+    owning ``own`` of ``num_parts`` partitions, plus a margin of
+    max(expected / 16, 64 * sqrt(expected)) rows, rounded up to
+    ``_SHARE_QUANTUM * lcm``.  A binomial share is within 64 sigma of
+    its mean, and so on rung 0, for any owned fraction.  A larger share
+    (a hot partition) takes rung k, the base doubled k times, up to the
+    whole side rounded to ``lcm``.  Never below ``rows``.
+    """
+    q = _SHARE_QUANTUM * lcm
+    top = _round_up(max(n, rows, 1), lcm)
+    expected = n * own / num_parts
+    margin = max(expected / 16, 64 * math.sqrt(expected))
+    cap = min(_round_up(max(math.ceil(expected + margin), 1), q), top)
+    rung = 0
+    while cap < rows:
+        cap = min(2 * cap, top)
+        rung += 1
+    return cap, rung
+
+
 # Fault-injection hook: ``repro.engine.faults.install`` plants its
 # ``maybe_fault`` here (set back to None on uninstall), so the hot path
 # costs one load and one branch when no injector is active, and this
@@ -746,20 +775,29 @@ class PhjCoProcessorMixin:
             # Each side's columns cross to the host once, on the first
             # group's exchange; the other group slices the same host copy.
             host_cols = {}
-            for grp, sel in ((self.c, lambda pid: pid < own),
-                             (self.g, lambda pid: pid >= own)):
-                if (own == 0 and grp is self.c) or (own == num_parts
-                                                    and grp is self.g):
+            for grp, owned, sel in (
+                    (self.c, own, lambda pid: pid < own),
+                    (self.g, num_parts - own, lambda pid: pid >= own)):
+                if owned == 0:
                     continue
                 sub = {}
-                with self.tracer.span("exchange", group=grp.name):
+                with self.tracer.span("exchange", group=grp.name) as sp:
                     for tag in ("R", "S"):
                         rel = parts[tag]
                         pid = radix_of(rel.key, shift=0, bits=total_bits)
                         mask = self._pull(sel(pid), "phj.exchange.mask",
                                           f"{tag}.pid")
                         idx = np.nonzero(mask)[0]
-                        m = _round_up(max(len(idx), 1), self.lcm)
+                        # The share's capacity, not its row count, is the
+                        # shape: fresh relations reuse the same program.
+                        m, rung = _share_capacity(rel.size, owned, num_parts,
+                                                  len(idx), self.lcm)
+                        if sp is not None:
+                            sp.set(**{f"rows_{tag}": len(idx),
+                                      f"capacity_{tag}": m})
+                        if self.metrics is not None:
+                            self.metrics.inc("phj_share_rung", group=grp.name,
+                                             side=tag, rung=rung)
                         sent = (self.BUILD_PAD_KEY if tag == "R"
                                 else self.PROBE_PAD_KEY)
                         if tag not in host_cols:

@@ -99,3 +99,39 @@ def test_scan_allocator(rng):
     st = alloc_stats(sizes, tile=256, block_items=256)
     assert st.global_units == 4096 // 256           # one claim per tile
     assert basic_alloc_units(sizes) == int((sz > 0).sum())
+
+
+@pytest.mark.parametrize("n, own, num_parts, lcm, rows", [
+    (4096, 1, 2, 1, 4096),
+    (4096, 4, 16, 1, 0),
+    (12288, 3, 4, 3, 9216),
+    (1 << 15, 4, 16, 1, 20480),
+    (1 << 16, 64, 128, 2, 1),
+    (1 << 20, 7, 32, 4, 1 << 20),
+    (1 << 24, 4096, 8192, 1, 8390429),
+    (1 << 24, 4096, 8192, 1, 1 << 24),
+    (1 << 24, 1, 8192, 1, 3 << 20),
+    (1 << 24, 8191, 8192, 8, 1 << 23),
+])
+def test_share_capacity(n, own, num_parts, lcm, rows):
+    """The share's capacity is fixed before the data: a binomial share
+    stays on rung 0, a larger one climbs a few rungs, never past the
+    side's size, and no row is ever cut off."""
+    import math
+    from repro.core.coprocess import _round_up, _share_capacity
+    top = _round_up(n, lcm)
+    cap, _ = _share_capacity(n, own, num_parts, rows, lcm)
+    assert rows <= cap <= top and cap % lcm == 0
+    p = own / num_parts
+    mean, sigma = n * p, math.sqrt(n * p * (1 - p))
+    assert _share_capacity(n, own, num_parts,
+                           min(n, int(mean + 64 * sigma)), lcm)[1] == 0
+    # Capacity is a step function of rows: walk its steps over [0, n].
+    caps, r = [], 0
+    while r <= n:
+        c, k = _share_capacity(n, own, num_parts, r, lcm)
+        assert c >= r and k == len(caps)
+        caps.append(c)
+        r = c + 1
+    assert caps == sorted(set(caps)) and caps[-1] == top
+    assert len(caps) <= math.log2(num_parts / own) + 2

@@ -393,9 +393,10 @@ def test_phj_exchange_bytes_match_shape_reckoning():
     """Every crossing of a CPU PHJ, reckoned from its shapes: partition
     pieces down and back up, the ownership split (a bool pid mask per
     side per group and both int32 columns per side once down, each
-    group's share up),
+    group's share up at its capacity),
     and both groups' results down and back up for the concat."""
     from repro.core import CoProcessor, uniform_relation
+    from repro.core.coprocess import _share_capacity
     from repro.core.relation import radix_of
     metrics = MetricsRegistry()
     cp = CoProcessor()
@@ -406,13 +407,16 @@ def test_phj_exchange_bytes_match_shape_reckoning():
     cp.phj(b, s, schedule=sched, shj_bits=2, max_out=max_out,
            partition_ratio=0.5, join_ratio=0.5)
     bits = sum(sched)
-    own = cp._cut(1 << bits, 0.5)
+    num_parts = 1 << bits
+    own = cp._cut(num_parts, 0.5)
     groups = 2                     # both own partitions at join_ratio 0.5
     share_up = 0
     for rel in (b, s):
         pid = np.asarray(radix_of(rel.key, shift=0, bits=bits))
-        for mask in (pid < own, pid >= own):
-            share_up += 8 * _round_up(max(int(mask.sum()), 1), cp.lcm)
+        for owned, mask in ((own, pid < own), (num_parts - own, pid >= own)):
+            cap, _ = _share_capacity(rel.size, owned, num_parts,
+                                     int(mask.sum()), cp.lcm)
+            share_up += 8 * cap
     mo = _round_up(max_out, 8) + 64
     result = 2 * 4 * mo + 4        # probe_rid + build_rid + count
     want = {

@@ -11,7 +11,8 @@ import threading
 
 import pytest
 
-from repro.core import CoProcessor, uniform_relation, unique_relation
+from repro.core import (CoProcessor, Relation, join_oracle, uniform_relation,
+                        unique_relation)
 from repro.engine import (JoinQuery, JoinQueryService, QueryPlanner, Tenant)
 from repro.obs import (CostAudit, MetricsRegistry, NULL_TRACER, NullTracer,
                        Tracer)
@@ -502,6 +503,72 @@ def test_first_launch_carries_compiles_repeat_does_not():
     # The launch span is the one per-program record: no registry copy.
     assert not any(k.startswith(("compiles", "compile_s"))
                    for k in svc.metrics.snapshot())
+    svc.close()
+
+
+def _share_rungs(metrics) -> dict:
+    """``{group + side: (rung, count)}`` of the ``phj_share_rung`` series."""
+    out = {}
+    for labels, v in metrics.counter_series("phj_share_rung").items():
+        lab = dict(labels)
+        out[lab["group"] + lab["side"]] = (lab["rung"], v)
+    return out
+
+
+def test_fresh_relations_reuse_the_join_program():
+    """Shares are shaped by a capacity fixed before the data, so a second
+    query on fresh relations of the same sizes compiles no ``phj_join``."""
+    n = 1 << 15
+    svc = JoinQueryService(cp=CoProcessor(), planner=_phj_planner(),
+                           num_workers=0)
+
+    def run(qid, seed):
+        b = uniform_relation(n, seed=seed)
+        s = uniform_relation(n, key_range=n, seed=seed + 1)
+        out = svc.execute(JoinQuery(build=b, probe=s, query_id=qid,
+                                    max_out=4 * n + 1024))
+        assert out.plan.algorithm == "phj"
+        assert (out.result.valid_pairs() == join_oracle(b, s)).all()
+        return out
+
+    run(1, seed=51)
+    before = svc.metrics.counter_value("phj_share_rung")
+    again = run(2, seed=61)
+    launches = [s for s in again.trace if s["name"] == "launch"
+                and s["attrs"]["program"] == "phj_join"]
+    assert launches
+    assert not any("compiles" in s["attrs"] for s in launches)
+    exchanges = [s["attrs"] for s in again.trace if s["name"] == "exchange"]
+    assert len(exchanges) == len(launches)
+    for a in exchanges:
+        for side in ("R", "S"):
+            assert 0 < a[f"rows_{side}"] <= a[f"capacity_{side}"]
+    # One count per group and side that owns partitions, all on rung 0.
+    assert svc.metrics.counter_value("phj_share_rung") - before == \
+        2 * len(exchanges)
+    rungs = _share_rungs(svc.metrics)
+    assert {r for r, _ in rungs.values()} == {0}
+    assert all(v == 2 for _, v in rungs.values())
+    svc.close()
+
+
+def test_hot_key_share_climbs_a_rung_and_stays_exact():
+    """Half the build rows on key 0 (partition 0, owned by C): C's share
+    outgrows the base rung, climbs past it, and the answer stays exact."""
+    n = 1 << 15
+    b = uniform_relation(n, seed=71)
+    b = Relation(b.rid, b.key.at[: n // 2].set(0))
+    s = uniform_relation(n, key_range=n, seed=72)
+    exp = join_oracle(b, s)
+    svc = JoinQueryService(cp=CoProcessor(), planner=_phj_planner(),
+                           num_workers=0)
+    out = svc.execute(JoinQuery(build=b, probe=s, query_id=1,
+                                max_out=len(exp) + 1024))
+    assert out.plan.algorithm == "phj" and 0 < out.plan.join_ratio < 1
+    assert (out.result.valid_pairs() == exp).all()
+    rungs = _share_rungs(svc.metrics)
+    assert rungs["CR"][0] >= 1
+    assert rungs["CS"][0] == rungs["GR"][0] == rungs["GS"][0] == 0
     svc.close()
 
 
