@@ -412,14 +412,15 @@ def four_chips(seed: int, clock: CompileClock, devices) -> None:
     g_outputs: list = []
     watch_group_outputs(cp4.g, g_outputs)
     # Placement, not plan quality, is under test: every query is DD, so
-    # both groups take a share.
-    planner = QueryPlanner(allowed_schemes=("DD",))
+    # both groups take a share.  One planner per service.
     dd_query = make_workload("uniform", num_queries=1,
                              base_tuples=MIXED_STREAMS[0][0],
                              seed=seed + 10)[0]
     exp_dd = join_oracle(dd_query.build, dd_query.probe)
-    with JoinQueryService(cp=cp4, planner=planner, num_workers=1) as svc4, \
-            JoinQueryService(cp=cp1, planner=planner, num_workers=1) as svc1:
+    with JoinQueryService(cp=cp4, planner=QueryPlanner(
+            allowed_schemes=("DD",)), num_workers=1) as svc4, \
+            JoinQueryService(cp=cp1, planner=QueryPlanner(
+                allowed_schemes=("DD",)), num_workers=1) as svc1:
         outcomes = {}
         for name, svc in (("C1/G3", svc4), ("one chip", svc1)):
             clock.take()
@@ -429,6 +430,8 @@ def four_chips(seed: int, clock: CompileClock, devices) -> None:
             wall = time.perf_counter() - t0
             if not np_equal(o.result.valid_pairs(), exp_dd):
                 fail(f"DD query on {name} differs from join_oracle")
+            if o.plan.scheme != "DD":
+                fail(f"DD query on {name} planned {o.plan.scheme}")
             phase_line(f"b_dd[{name}]", wall, int(o.result.count),
                        plan_str([o]), clock.take(),
                        size=f"{dd_query.build.size}x{dd_query.probe.size}")

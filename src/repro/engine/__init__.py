@@ -33,7 +33,8 @@ from .admission import (AdmissionController, AdmissionDecision,
                         Backpressure, Tenant, TenantFairQueue, jain_index)
 from .faults import (FaultInjected, FaultInjector, FaultSpec, injected,
                      install as install_fault_injector, maybe_fault)
-from .planner import (EXECUTABLE_SCHEMES, SCHEMES, QueryPlan, QueryPlanner)
+from .planner import (EXECUTABLE_SCHEMES, SCHEMES, PlanScope, QueryPlan,
+                      QueryPlanner)
 from .resilience import (BreakerBoard, BudgetEnforcer, BudgetExceeded,
                          Cancelled, DeadlineExceeded, QueryContext,
                          RetryPolicy)

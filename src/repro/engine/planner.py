@@ -100,6 +100,25 @@ class QueryPlan:
         return float(np.mean(rs)) if rs else 0.5
 
 
+@dataclasses.dataclass(frozen=True)
+class PlanScope:
+    """What one service's plans are made for, passed with every choice:
+    whether its C and G groups hold the same devices (one chip), and the
+    registry that counts its ``plan_changes``.  A planner shared by
+    services on different groups prices each service's queries for its
+    own groups; the default is two distinct groups and no registry."""
+
+    shared_groups: bool = False
+    metrics: object = dataclasses.field(default=None, compare=False)
+
+    @classmethod
+    def of(cls, cp, metrics=None) -> "PlanScope":
+        return cls(list(cp.c.devices) == list(cp.g.devices), metrics)
+
+
+DISTINCT_GROUPS = PlanScope()
+
+
 def _unit_parts(device: DeviceSpec, cost) -> tuple[float, float]:
     """(non-random, random-access) components of seconds/item (Eq. 3)."""
     non_rand = (cost.ops_per_item / device.ops_per_s
@@ -284,20 +303,22 @@ class QueryPlanner:
                                np.asarray(outb, np.float64), self.link,
                                discrete=self.discrete)
 
-    def _sweep(self, key, series, x, *, rand_scale: float):
+    def _sweep(self, key, series, x, *, rand_scale: float,
+               schemes: tuple[str, ...]):
         """Memoized scheme sweep (hot-table traffic re-plans same shapes).
 
         The sweep prices the *unscaled* model, so the chosen ratios — and
         therefore the compiled slice shapes — are stable; online scales
         adjust candidate totals afterwards, per scheme.
         """
-        cache_key = (key, tuple(x), round(rand_scale, 4), self.delta)
+        cache_key = (key, tuple(x), round(rand_scale, 4), self.delta,
+                     schemes)
         with self._lock:
             hit = self._sweep_cache.get(cache_key)
         if hit is not None:
             return hit
         m = self._series_model(series, x, rand_scale=rand_scale)
-        out = m.scheme_sweep(delta=self.delta, schemes=self.allowed_schemes)
+        out = m.scheme_sweep(delta=self.delta, schemes=schemes)
         with self._lock:
             if len(self._sweep_cache) > 512:
                 self._sweep_cache.clear()
@@ -305,8 +326,24 @@ class QueryPlanner:
         return out
 
     # -- candidate estimates -------------------------------------------------
+    def _schemes(self, scope: PlanScope) -> tuple[str, ...]:
+        """The schemes an SHJ stage or a group-by is offered.
+
+        Where C and G hold the same devices (one chip), a split between
+        them is the same work plus the split, and a CPU_ONLY/GPU_ONLY
+        choice only picks which group's programs compile: the offer is
+        the one single-group scheme of ``allowed_schemes``, G's first, so
+        a plan is a function of the query's shapes and the chip.  A
+        catalog with no single-group scheme is offered as it is."""
+        if scope.shared_groups:
+            for s in ("GPU_ONLY", "CPU_ONLY"):
+                if s in self.allowed_schemes:
+                    return (s,)
+        return self.allowed_schemes
+
     def _shj_candidates(self, build_n: int, probe_n: int, cached: bool,
-                        kind: str = "inner"):
+                        kind: str = "inner",
+                        scope: PlanScope = DISTINCT_GROUPS):
         rs = self.table_rand_scale(build_n)
         # Semi/anti emit match flags instead of expanding matches: the p4
         # payload gather (2 random accesses/tuple) drops out of the series,
@@ -317,14 +354,17 @@ class QueryPlanner:
                        else PROBE_SERIES.steps)
         probe_tag = ("shj_probe" if kind == "inner"
                      else f"shj_probe[{kind}]")
+        schemes = self._schemes(scope)
         probe = self._sweep(probe_tag, probe_steps,
-                            [probe_n] * len(probe_steps), rand_scale=rs)
+                            [probe_n] * len(probe_steps), rand_scale=rs,
+                            schemes=schemes)
         if cached:
             build = None
         else:
             build = self._sweep("shj_build", BUILD_SERIES.steps,
-                                [build_n] * 4, rand_scale=rs)
-        for scheme in self.allowed_schemes:
+                                [build_n] * 4, rand_scale=rs,
+                                schemes=schemes)
+        for scheme in schemes:
             rp, tp = probe[scheme]
             rb, tb = build[scheme] if build else (rp, 0.0)
             # Per-scheme online scales: a PL plan's boundary shuffles and a
@@ -339,7 +379,19 @@ class QueryPlanner:
                 cached=cached, est_s=tb + tp, est_build_s=tb,
                 est_probe_s=tp, kind=kind)
 
-    def _phj_candidate(self, build_n: int, probe_n: int) -> QueryPlan | None:
+    def _offers_phj(self, build_n: int, kind: str,
+                    scope: PlanScope) -> bool:
+        """PHJ is a candidate for inner joins.  On shared groups its split
+        halves the estimate but not the work, and partitioning pays only
+        in locality, which a build table that fits the cache does not
+        need: there PHJ is not offered, and SHJ is the one plan.  A build
+        past the cache keeps PHJ, priced as two groups."""
+        if not (self.allow_phj and kind == "inner"):
+            return False
+        return (not scope.shared_groups
+                or build_n * TABLE_BYTES_PER_TUPLE > self.cache_bytes)
+
+    def _phj_candidate(self, build_n: int, probe_n: int) -> QueryPlan:
         plan = self.pass_planner.plan(build_n)
         total_bits = plan.total_bits
         part_scale = self.online.scale_for("phj_partition")
@@ -378,7 +430,8 @@ class QueryPlanner:
     def choose(self, build_n: int, probe_n: int, *, max_out: int,
                cached: bool = False, expect_reuse: bool = False,
                c_load: float = 0.0, g_load: float = 0.0,
-               kind: str = "inner", record: bool = True) -> QueryPlan:
+               kind: str = "inner", record: bool = True,
+               scope: PlanScope = DISTINCT_GROUPS) -> QueryPlan:
         """Plan one query.
 
         ``kind``         — join-variant semantics; non-inner kinds run over
@@ -398,10 +451,14 @@ class QueryPlanner:
         plan (and therefore its compiled executables) is reused until the
         online calibration moves materially (``OnlineUnitCosts.version``).
         Load bias applies at (re)planning moments, not on every repeat of
-        a hot signature.
+        a hot signature.  ``scope`` is the calling service's groups and
+        registry (``PlanScope``).
         """
+        if scope.shared_groups:
+            c_load = g_load = 0.0       # one chip: the same under any plan
         sig = (build_n, probe_n, cached, expect_reuse,
-               self._load_bucket(c_load, g_load), kind)
+               self._load_bucket(c_load, g_load), kind,
+               scope.shared_groups)
 
         def make_candidates():
             # A resident table does not *force* probe-only: at sizes
@@ -409,11 +466,9 @@ class QueryPlanner:
             # PHJ can beat probing it — the sweep arbitrates (plan.cached
             # marks the winner).
             cands = list(self._shj_candidates(build_n, probe_n, cached,
-                                              kind))
-            if self.allow_phj and kind == "inner":
-                phj = self._phj_candidate(build_n, probe_n)
-                if phj is not None:
-                    cands.append(phj)
+                                              kind, scope))
+            if self._offers_phj(build_n, kind, scope):
+                cands.append(self._phj_candidate(build_n, probe_n))
             return cands
 
         def effective(p: QueryPlan) -> float:
@@ -431,7 +486,7 @@ class QueryPlanner:
             keep_key=lambda p: (p.algorithm, p.scheme, p.cached),
             count_key=lambda p: (p.algorithm,
                                  "cached" if cached else p.scheme),
-            record=record)
+            record=record, kind="join", metrics=scope.metrics)
         if from_cache:
             return dataclasses.replace(plan, max_out=int(max_out))
         plan.max_out = int(max_out)
@@ -439,7 +494,8 @@ class QueryPlanner:
 
     def choose_degraded(self, build_n: int, probe_n: int, *, max_out: int,
                         cached: bool = False, kind: str = "inner",
-                        record: bool = True) -> QueryPlan:
+                        record: bool = True,
+                        scope: PlanScope = DISTINCT_GROUPS) -> QueryPlan:
         """The *cheapest* realizable plan — deadline-degraded execution.
 
         Admission uses this when a query's preferred plan already misses
@@ -450,22 +506,21 @@ class QueryPlanner:
         variant the usual winner.  Sticky under its own signature, so
         degraded traffic reuses compiled executables like any other.
         """
-        sig = ("degraded", build_n, probe_n, cached, kind)
+        sig = ("degraded", build_n, probe_n, cached, kind,
+               scope.shared_groups)
 
         def make_candidates():
             cands = list(self._shj_candidates(build_n, probe_n, cached,
-                                              kind))
-            if self.allow_phj and kind == "inner":
-                phj = self._phj_candidate(build_n, probe_n)
-                if phj is not None:
-                    cands.append(phj)
+                                              kind, scope))
+            if self._offers_phj(build_n, kind, scope):
+                cands.append(self._phj_candidate(build_n, probe_n))
             return cands
 
         plan, from_cache = self._sticky_choose(
             sig, make_candidates, lambda p: p.est_s,
             keep_key=lambda p: (p.algorithm, p.scheme, p.cached),
             count_key=lambda p: (p.algorithm, "degraded"),
-            record=record)
+            record=record, kind="degraded", metrics=scope.metrics)
         if from_cache:
             return dataclasses.replace(plan, max_out=int(max_out))
         plan.max_out = int(max_out)
@@ -482,14 +537,17 @@ class QueryPlanner:
         return 1 if c_load > g_load else -1
 
     def _sticky_choose(self, sig, make_candidates, effective, *,
-                       keep_key, count_key, record: bool = True):
+                       keep_key, count_key, record: bool = True,
+                       kind: str, metrics=None):
         """Sticky cost-model choice shared by join and group-by planning.
 
         A cached plan for ``sig`` is reused until the online calibration
         version moves; on a re-price, the incumbent (matched by
         ``keep_key``) keeps its compiled executables unless the challenger
         beats it by ``replan_margin`` (near-tie flips trade compiled code
-        for XLA recompiles).  Returns ``(plan, from_cache)``.
+        for XLA recompiles).  A challenger that displaces it counts one
+        ``plan_changes{kind}`` in ``metrics``, the calling service's
+        registry: the event that compiles new programs.  Returns ``(plan, from_cache)``.
         ``record=False`` skips the plan-count bookkeeping — admission-time
         pricing must not inflate the execution mix the benches report.
         """
@@ -510,6 +568,8 @@ class QueryPlanner:
             if keep and not self.replan_beats(effective(best),
                                               effective(keep[0])):
                 best = keep[0]
+            if keep_key(best) != keep_key(prev) and metrics is not None:
+                metrics.inc("plan_changes", kind=kind)
         with self._lock:
             if len(self._plan_cache) > 512:
                 self._plan_cache.clear()
@@ -559,7 +619,8 @@ class QueryPlanner:
     # -- group-by aggregation (ops subsystem) --------------------------------
     def _groupby_sweep(self, n: int):
         return self._sweep("groupby_agg", BUILD_SERIES.steps, [n] * 4,
-                           rand_scale=self.table_rand_scale(n))
+                           rand_scale=self.table_rand_scale(n),
+                           schemes=("CPU_ONLY", "GPU_ONLY"))
 
     def _groupby_single(self, n: int, scheme: str,
                         sweep=None) -> QueryPlan:
@@ -620,24 +681,31 @@ class QueryPlanner:
             join_ratio=float(agg_ratio))
 
     def choose_groupby(self, n: int, *, c_load: float = 0.0,
-                       g_load: float = 0.0,
-                       record: bool = True) -> QueryPlan:
+                       g_load: float = 0.0, record: bool = True,
+                       scope: PlanScope = DISTINCT_GROUPS) -> QueryPlan:
         """Plan one group-by aggregation over ``n`` tuples.
 
         Candidates follow ``allowed_schemes``: whole-relation aggregation
         on either single group (CPU_ONLY / GPU_ONLY), the row-split
         separate-partials DD, and the radix-partitioned DD split under the
         same ``PassPlanner`` schedule and ``coproc_margin`` handicap as
-        PHJ.  Plans are sticky per (n, load bucket) like join plans.
+        PHJ.  On shared groups the one candidate is the single-group
+        scheme ``_schemes`` offers.  Plans are sticky per (n, load bucket)
+        like join plans.
         """
-        sig = ("groupby", n, self._load_bucket(c_load, g_load))
+        if scope.shared_groups:
+            c_load = g_load = 0.0
+        sig = ("groupby", n, self._load_bucket(c_load, g_load),
+               scope.shared_groups)
 
         def make_candidates():
             sweep = self._groupby_sweep(n)
+            schemes = self._schemes(scope)
             cands = [self._groupby_single(n, s, sweep)
-                     for s in ("CPU_ONLY", "GPU_ONLY")
-                     if s in self.allowed_schemes]
-            if "DD" in self.allowed_schemes:
+                     for s in ("CPU_ONLY", "GPU_ONLY") if s in schemes]
+            if scope.shared_groups and cands:
+                return cands
+            if "DD" in schemes:
                 cands.append(self._groupby_separate(n))
             if self.allow_phj:
                 cands.append(self._groupby_coproc(n))
@@ -653,7 +721,8 @@ class QueryPlanner:
         plan, _ = self._sticky_choose(
             sig, make_candidates, effective,
             keep_key=lambda p: (p.scheme, bool(p.schedule)),
-            count_key=lambda p: ("groupby", p.scheme), record=record)
+            count_key=lambda p: ("groupby", p.scheme), record=record,
+            kind="groupby", metrics=scope.metrics)
         return plan
 
     # -- feedback (satellite: close the calibration loop online) -----------

@@ -46,7 +46,7 @@ from .admission import (AdmissionController, Backpressure, QueueFull,
                         Tenant, TenantFairQueue)
 from .faults import (FaultInjected, active as _faults_active,
                      layout_checksum, maybe_corrupt, maybe_fault)
-from .planner import QueryPlan, QueryPlanner
+from .planner import PlanScope, QueryPlan, QueryPlanner
 from .resilience import (BreakerBoard, BudgetEnforcer, BudgetExceeded,
                          DeadlineExceeded, QueryContext, RetryPolicy)
 from .table_cache import (BuildTableCache, partition_layout_key,
@@ -318,6 +318,10 @@ class JoinQueryService:
             self.cp.ledger = self.ledger
         if getattr(self.cp, "metrics", None) is None:
             self.cp.metrics = self.metrics
+        # Every plan this service asks for is priced for the groups it
+        # runs on (one plan per query where C and G are the same chip),
+        # and its plan changes count in this service's registry.
+        self.plan_scope = PlanScope.of(self.cp, self.metrics)
         # Deadline-aware multi-tenant admission: the controller prices
         # admit/degrade/shed decisions from planner estimates; the queue
         # serves tenants weighted-fair, EDF within each.  ``fifo`` mode is
@@ -744,13 +748,15 @@ class JoinQueryService:
                 # Deadline-degraded: admission promised the cheapest plan.
                 plan = self.planner.choose_degraded(
                     build_n, probe_n, max_out=max_out,
-                    cached=table is not None, kind=q.kind)
+                    cached=table is not None, kind=q.kind,
+                    scope=self.plan_scope)
             else:
                 plan = self.planner.choose(
                     build_n, probe_n, max_out=max_out,
                     cached=table is not None,
                     expect_reuse=seen and table is None,
-                    c_load=c_load, g_load=g_load, kind=q.kind)
+                    c_load=c_load, g_load=g_load, kind=q.kind,
+                    scope=self.plan_scope)
         if qspan is not None:
             # Ambient for the phase spans opened below on this thread.
             qspan.set(algorithm=plan.algorithm, scheme=plan.scheme)
@@ -960,7 +966,8 @@ class JoinQueryService:
             c_load, g_load = self._loads["C"], self._loads["G"]
         with self.tracer.span("plan"):
             plan = self.planner.choose_groupby(n, c_load=c_load,
-                                               g_load=g_load)
+                                               g_load=g_load,
+                                               scope=self.plan_scope)
         if qspan is not None:
             qspan.set(algorithm=plan.algorithm, scheme=plan.scheme)
         plan_key = (plan.algorithm, plan.scheme)
@@ -1058,14 +1065,16 @@ class JoinQueryService:
         if isinstance(q, GroupByQuery):
             result = self._reference_groupby_result(q)
             plan = self.planner.choose_groupby(q.keys.size, c_load=0.0,
-                                               g_load=0.0, record=False)
+                                               g_load=0.0, record=False,
+                                               scope=self.plan_scope)
         else:
             max_out = (q.max_out if q.max_out is not None
                        else 4 * q.probe.size + 1024)
             result = self._reference_join_result(q, max_out)
             plan = self.planner.choose_degraded(
                 q.build.size, q.probe.size, max_out=max_out,
-                cached=False, kind=q.kind, record=False)
+                cached=False, kind=q.kind, record=False,
+                scope=self.plan_scope)
         timing = Timing(tracer=self.cp.tracer)
         timing.notes["reference_path"] = True
         wall = time.perf_counter() - t0
@@ -1210,7 +1219,7 @@ class JoinQueryService:
             if isinstance(q, GroupByQuery):
                 plan = self.planner.choose_groupby(
                     q.keys.size, c_load=c_load, g_load=g_load,
-                    record=False)
+                    record=False, scope=self.plan_scope)
             else:
                 build_n, probe_n = q.build.size, q.probe.size
                 max_out = (q.max_out if q.max_out is not None
@@ -1227,7 +1236,7 @@ class JoinQueryService:
                     cached=table is not None,
                     expect_reuse=seen and table is None,
                     c_load=c_load, g_load=g_load, kind=q.kind,
-                    record=False)
+                    record=False, scope=self.plan_scope)
             return float(plan.est_s), float(plan.c_share)
         except Exception:
             return 0.0, 0.5    # unpriceable -> admit-by-count semantics
@@ -1247,7 +1256,7 @@ class JoinQueryService:
             plan = self.planner.choose_degraded(
                 build_n, probe_n, max_out=max_out,
                 cached=self.cache.peek(key) is not None, kind=q.kind,
-                record=False)
+                record=False, scope=self.plan_scope)
             return float(plan.est_s)
         except Exception:
             return None

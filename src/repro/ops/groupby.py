@@ -118,23 +118,18 @@ def grouped_agg(rel: Relation, values: jax.Array, *, num_slots: int,
     return ukeys, cnt, sm, mn, mx, num_groups
 
 
-def _gather_values(values, rid) -> np.ndarray:
-    """values[rid] with pad rows (rid == -1) mapped to 0.
+def _gather_values(values: jax.Array, rid: jax.Array) -> jax.Array:
+    """values[rid] with pad rows (rid == -1) mapped to 0, on the device."""
+    safe = jnp.clip(rid, 0, max(values.shape[0] - 1, 0))
+    out = (jnp.take(values, safe, axis=0) if values.shape[0]
+           else jnp.zeros_like(rid))
+    return jnp.where(rid >= 0, out, 0).astype(jnp.int32)
 
-    ``values`` may be a device array (the query pipeline's fused hand-off
-    passes the sink's value column device-resident): the gather then runs
-    on device instead of forcing a host round trip.
-    """
-    if isinstance(values, jax.Array):
-        r = jnp.asarray(rid)
-        safe = jnp.clip(r, 0, max(values.shape[0] - 1, 0))
-        out = (jnp.take(values, safe, axis=0) if values.shape[0]
-               else jnp.zeros_like(r))
-        return jnp.where(r >= 0, out, 0).astype(jnp.int32)
-    r = np.asarray(rid)
-    safe = np.clip(r, 0, max(values.shape[0] - 1, 0))
-    out = values[safe] if values.shape[0] else np.zeros_like(r)
-    return np.where(r >= 0, out, 0).astype(np.int32)
+
+def _pull_groups(bnd, outs) -> list:
+    """Each group's padded group list, pulled to the host."""
+    return bnd.pull(outs, site="groupby.collect", cause="result",
+                    column="groups")
 
 
 def _merge_partials(a: GroupByResult, b: GroupByResult) -> GroupByResult:
@@ -207,14 +202,20 @@ def groupby_coprocessed(cp: CoProcessor, rel: Relation, values, *,
     from repro.core.partition import radix_partition_scheduled
 
     timing = Timing(tracer=cp.tracer)
-    if isinstance(values, jax.Array):
-        values = values.astype(jnp.int32)
-    else:
-        values = np.ascontiguousarray(np.asarray(values, dtype=np.int32))
+    bnd = cp.boundary
     if rel.size == 0:
         timing.phase_s["partition"] = 0.0
         timing.phase_s["agg"] = 0.0
         return _collect([], wrap32=wrap32), timing
+    if isinstance(values, jax.Array):
+        values = values.astype(jnp.int32)
+    else:
+        # A host value column crosses once; every gather below runs on
+        # the device.
+        values = bnd.push(
+            np.ascontiguousarray(np.asarray(values, dtype=np.int32)),
+            jnp.asarray, site="groupby.values", cause="base_upload",
+            column="values")
     rel = cp.pad_relation(rel, GROUP_PAD_KEY)
     if schedule:
         timing.notes["schedule"] = list(schedule)
@@ -244,9 +245,14 @@ def groupby_coprocessed(cp: CoProcessor, rel: Relation, values, *,
                     f = cp.g.jit(("gb_part", n - cut, schedule),
                                  partial(part_fn, mesh=cp.g.mesh))
                     pieces.append(f(cp.g.put_items(rel.take(cut, n))))
-                pieces = [jax.tree.map(jax.device_get, x) for x in pieces]
-                rel = Relation(jnp.concatenate([x.rid for x in pieces]),
-                               jnp.concatenate([x.key for x in pieces]))
+                pieces = bnd.pull(pieces, site="groupby.partition",
+                                  cause="exchange", column="rid+key")
+                rel = bnd.push(
+                    pieces, lambda ps: Relation(
+                        jnp.concatenate([x.rid for x in ps]),
+                        jnp.concatenate([x.key for x in ps])),
+                    site="groupby.partition", cause="exchange",
+                    column="rid+key")
         if ctx is not None:
             ctx.check("agg")
         with timing.phase("agg"):
@@ -255,7 +261,11 @@ def groupby_coprocessed(cp: CoProcessor, rel: Relation, values, *,
             num_parts = 1 << total_bits
             own = cp._cut(num_parts, agg_ratio)
             pid = radix_of(rel.key, shift=0, bits=total_bits)
-            pid_host = np.asarray(pid)
+            pid_host = bnd.pull(pid, site="groupby.exchange.pid",
+                                cause="exchange", column="pid")
+            rid_host, key_host = bnd.pull(
+                (rel.rid, rel.key), site="groupby.exchange.rows",
+                cause="exchange", column="rid+key")
             outs = []
             for grp, mask in ((cp.c, pid_host < own),
                               (cp.g, pid_host >= own)):
@@ -266,20 +276,23 @@ def groupby_coprocessed(cp: CoProcessor, rel: Relation, values, *,
                 m = _round_up(max(len(idx), 1), cp.lcm)
                 rid = np.full(m, int(INVALID), np.int32)
                 key = np.full(m, GROUP_PAD_KEY, np.int32)
-                rid[:len(idx)] = np.asarray(rel.rid)[idx]
-                key[:len(idx)] = np.asarray(rel.key)[idx]
+                rid[:len(idx)] = rid_host[idx]
+                key[:len(idx)] = key_host[idx]
                 if cp.discrete:
                     cp._bus_delay(len(idx) * 8 // 2, timing)
-                vals = _gather_values(values, rid)
+                # The share crosses to its group once; its own rids
+                # gather its values from the group's copy of the column.
+                share = bnd.push(
+                    Relation(rid, key), grp.put_items,
+                    site="groupby.exchange.share", cause="exchange",
+                    column="rid+key")
+                vals = _gather_values(grp.put_shared(values), share.rid)
                 f = grp.jit(("gb_agg", m, interpret, wrap32),
                             partial(grouped_agg, num_slots=m,
                                     interpret=interpret, wrap32=wrap32,
                                     mesh=grp.mesh))
-                outs.append(f(grp.put_items(Relation(jnp.asarray(rid),
-                                                     jnp.asarray(key))),
-                              grp.put_items(jnp.asarray(vals))))
-            outs = [jax.tree.map(jax.device_get, o) for o in outs]
-            result = _collect(outs, wrap32=wrap32)
+                outs.append(f(share, grp.put_items(vals)))
+            result = _collect(_pull_groups(bnd, outs), wrap32=wrap32)
     else:
         timing.phase_s["partition"] = 0.0
         if ctx is not None:
@@ -294,7 +307,7 @@ def groupby_coprocessed(cp: CoProcessor, rel: Relation, values, *,
                 # below.
                 if cp.discrete:
                     cp._bus_delay((n - cut) * 8, timing)
-                vals = _gather_values(values, np.asarray(rel.rid))
+                vals = _gather_values(values, rel.rid)
                 outs = []
                 for grp, lo, hi in ((cp.c, 0, cut), (cp.g, cut, n)):
                     f = grp.jit(("gb_agg", hi - lo, interpret, wrap32),
@@ -302,19 +315,18 @@ def groupby_coprocessed(cp: CoProcessor, rel: Relation, values, *,
                                         interpret=interpret, wrap32=wrap32,
                                         mesh=grp.mesh))
                     outs.append(f(grp.put_items(rel.take(lo, hi)),
-                                  grp.put_items(jnp.asarray(vals[lo:hi]))))
+                                  grp.put_items(vals[lo:hi])))
             else:
                 grp = cp.c if cut == n else cp.g
                 if cp.discrete and grp is cp.g:
                     cp._bus_delay(n * 8, timing)
-                vals = _gather_values(values, np.asarray(rel.rid))
+                vals = _gather_values(values, rel.rid)
                 f = grp.jit(("gb_agg", n, interpret, wrap32),
                             partial(grouped_agg, num_slots=n,
                                     interpret=interpret, wrap32=wrap32,
                                     mesh=grp.mesh))
-                outs = [f(grp.put_items(rel),
-                          grp.put_items(jnp.asarray(vals)))]
-            outs = [jax.tree.map(jax.device_get, o) for o in outs]
+                outs = [f(grp.put_items(rel), grp.put_items(vals))]
+            outs = _pull_groups(bnd, outs)
             if len(outs) == 2:
                 with cp.tracer.span("merge"):
                     result = _merge_partials(

@@ -169,10 +169,13 @@ class _ScanView:
         pulling a device column back to compute its key."""
         fp = self._fp_memo.get(q)
         if fp is None:
-            h = hashlib.sha1()
-            h.update(self._raw(q).tobytes())
-            h.update(self._rows_token().encode())
-            fp = self._fp_memo[q] = h.hexdigest()
+            raw = self._raw(q)
+            with self._boundary.tracer.span("fingerprint", column=q,
+                                            bytes=raw.nbytes):
+                h = hashlib.sha1()
+                h.update(raw.tobytes())
+                h.update(self._rows_token().encode())
+                fp = self._fp_memo[q] = h.hexdigest()
         return fp
 
     def take(self, rows: np.ndarray) -> dict:
@@ -598,7 +601,8 @@ class PipelineExecutor:
                 p = self.service.planner.choose_degraded(
                     max(s.est_build, 1), max(s.est_probe, 1),
                     max_out=self._stage_capacity(s.est_out),
-                    cached=False, kind=s.kind, record=False)
+                    cached=False, kind=s.kind, record=False,
+                    scope=self.service.plan_scope)
                 total += float(p.est_s)
             if physical.agg_plan is not None:
                 total += float(physical.agg_plan.est_s)
@@ -641,7 +645,7 @@ class PipelineExecutor:
                       tenant: str = "default",
                       deadline_s: float | None = None) -> PipelineResult:
         if physical is None:
-            physical = self.optimizer.optimize(query)
+            physical = self._optimize(query)
         with self.service.tracer.span("pipeline", tenant=tenant,
                                       stages=len(physical.stages),
                                       handoff=self.handoff):
@@ -649,8 +653,7 @@ class PipelineExecutor:
                 tenant=tenant, est_s=physical.est_total_s,
                 deadline_s=deadline_s, query_id=next(self._qid),
                 degraded_est_s=self._degraded_total_s(physical))
-            base = {name: _ScanView(t, self.service.boundary)
-                    for name, t in query.tables.items()}
+            base = {name: self._scan(t) for name, t in query.tables.items()}
             # Residual (cycle-edge) filters on base tables apply at scan
             # time; the rest are grouped by the stage whose output they
             # filter.
@@ -716,6 +719,23 @@ class PipelineExecutor:
                                 tenant=tenant, deadline_at=deadline_at,
                                 degraded=degraded)
 
+    def _optimize(self, query: Query) -> PhysicalPlan:
+        """The optimizer's plan for the service's groups, spanned: it
+        reads every base column's distinct count on the host."""
+        with self.service.tracer.span("optimize",
+                                      joins=len(query.joins)):
+            return self.optimizer.optimize(
+                query, scope=self.service.plan_scope)
+
+    def _scan(self, table) -> _ScanView:
+        """A base table's scan and host filter, spanned."""
+        with self.service.tracer.span("scan", table=table.name,
+                                      rows_in=table.size) as sp:
+            view = _ScanView(table, self.service.boundary)
+            if sp is not None:
+                sp.set(rows_out=view.n)
+        return view
+
     def _run_adaptive(self, query, physical, base, inter, depth, *,
                       degraded, tenant, deadline_at):
         """Frontier-wave execution with observed-cardinality replans.
@@ -776,7 +796,8 @@ class PipelineExecutor:
             if not pending or worst_q < self.qerror_threshold:
                 continue
             replanned = self.optimizer.reprice_remaining(
-                query, executed_joins, [s.join for s in pending], observed)
+                query, executed_joins, [s.join for s in pending], observed,
+                scope=self.service.plan_scope)
             if replanned is None:
                 continue
             old_tail = [str(s.join) for s in pending]
@@ -933,9 +954,8 @@ class PipelineExecutor:
                 keys = np.concatenate([keys,
                                        np.full(pad, -4, np.int32)])
                 rid = np.concatenate([rid, np.full(pad, -1, np.int32)])
-            # Host hand-off into the sink: keys + rid + values H2D (the
-            # values cross inside the group-by, counted here with the
-            # rest).  Packed multi-column keys sourced from a device view
+            # Host hand-off into the sink: keys + rid + values H2D.
+            # Packed multi-column keys sourced from a device view
             # are packing traffic (``multicol_pack``), not a hand-off —
             # the fused path's ``handoff`` cause stays zero; with no join
             # stage before the sink, the keys are a base table's.
@@ -947,7 +967,7 @@ class PipelineExecutor:
                 (rid, keys, values),
                 lambda t: (Relation(jnp.asarray(t[0]),
                                     jnp.asarray(t[1], dtype=jnp.int32)),
-                           t[2]),
+                           jnp.asarray(t[2])),
                 site="groupby.sink", cause=cause, stage="groupby-sink",
                 column="keys+rid+values", tally=moved)
         gq = GroupByQuery(keys=rel, values=values, tag="groupby-sink",
@@ -1203,5 +1223,5 @@ class PipelineExecutor:
     # -- convenience ---------------------------------------------------------
     def run_optimized(self, query: Query):
         """(chosen physical plan, result) in one call."""
-        physical = self.optimizer.optimize(query)
+        physical = self._optimize(query)
         return physical, self.run(query, physical)

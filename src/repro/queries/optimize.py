@@ -36,7 +36,8 @@ from __future__ import annotations
 import dataclasses
 import itertools
 
-from repro.engine.planner import QueryPlan, QueryPlanner
+from repro.engine.planner import (DISTINCT_GROUPS, PlanScope, QueryPlan,
+                                  QueryPlanner)
 
 from .plan import Join, Query
 
@@ -170,7 +171,8 @@ class JoinOrderOptimizer:
     # -- pricing one order ---------------------------------------------------
     def price_order(self, query: Query, order, *,
                     observed_rows: dict | None = None,
-                    record: bool = True) -> PhysicalPlan:
+                    record: bool = True,
+                    scope: PlanScope = DISTINCT_GROUPS) -> PhysicalPlan:
         """Simulate ``order`` edge by edge, pricing every stage.
 
         ``observed_rows`` maps ``id(join_edge) -> exact output rows`` for
@@ -180,7 +182,8 @@ class JoinOrderOptimizer:
         — is priced from what the device actually measured instead of the
         estimate.  ``record=False`` keeps mid-pipeline re-pricing out of
         the planner's plan-count bookkeeping, exactly like admission-time
-        pricing.
+        pricing.  ``scope`` is the executing service's ``PlanScope``: every
+        stage and the group-by are priced for its groups.
         """
         observed = observed_rows or {}
         comps = {name: _base_component(query, name) for name in query.tables}
@@ -206,7 +209,7 @@ class JoinOrderOptimizer:
                 plan = self.planner.choose(
                     int(round(right.rows)), int(round(left.rows)),
                     max_out=max(64, int(out_rows * EST_OUT_SLACK) + 64),
-                    kind=join.kind, record=record)
+                    kind=join.kind, record=record, scope=scope)
                 deps = tuple(sorted(
                     {r for r in (left.ref,) if isinstance(r, int)}))
                 stage = PipelineStage(
@@ -237,7 +240,7 @@ class JoinOrderOptimizer:
                 plan = self.planner.choose(
                     int(round(right.rows)), int(round(left.rows)),
                     max_out=max(64, int(out_rows * EST_OUT_SLACK) + 64),
-                    kind=join.kind, record=record)
+                    kind=join.kind, record=record, scope=scope)
                 deps = tuple(sorted(
                     {r for r in (right.ref, left.ref)
                      if isinstance(r, int)}))
@@ -290,7 +293,7 @@ class JoinOrderOptimizer:
             plan = self.planner.choose(
                 int(round(build.rows)), int(round(probe.rows)),
                 max_out=max(64, int(out_rows * EST_OUT_SLACK) + 64),
-                record=record)
+                record=record, scope=scope)
             deps = tuple(sorted(
                 {r for r in (build.ref, probe.ref) if isinstance(r, int)}))
             stage = PipelineStage(
@@ -327,7 +330,7 @@ class JoinOrderOptimizer:
             # beyond that cardinality, so it cannot flip the ordering —
             # but it belongs in est_total_s for plan-vs-measured honesty).
             agg_plan = self.planner.choose_groupby(
-                max(1, int(round(final.rows))), record=record)
+                max(1, int(round(final.rows))), record=record, scope=scope)
             total += agg_plan.est_s
         return PhysicalPlan(stages=stages, order=tuple(order),
                             est_total_s=total, aggregate=query.aggregate,
@@ -348,7 +351,7 @@ class JoinOrderOptimizer:
             return [tuple(query.joins)]
         return [tuple(p) for p in itertools.permutations(query.joins)]
 
-    def _greedy_order(self, query: Query):
+    def _greedy_order(self, query: Query, scope: PlanScope):
         """Cheapest-marginal-stage-first (for beyond-exhaustive edge counts).
 
         At each step, price every remaining edge as the *next* stage of the
@@ -360,7 +363,7 @@ class JoinOrderOptimizer:
             best, best_cost = None, None
             for j in remaining:
                 candidate = chosen + [j]
-                plan = self.price_order(query, candidate)
+                plan = self.price_order(query, candidate, scope=scope)
                 cost = (plan.est_total_s, plan.stages[-1].est_out
                         if plan.stages else 0)
                 if best_cost is None or cost < best_cost:
@@ -369,32 +372,37 @@ class JoinOrderOptimizer:
             remaining.remove(best)
         return tuple(chosen)
 
-    def optimize(self, query: Query) -> PhysicalPlan:
-        """The cheapest priced order (exhaustive when small, else greedy)."""
+    def optimize(self, query: Query, *,
+                 scope: PlanScope = DISTINCT_GROUPS) -> PhysicalPlan:
+        """The cheapest priced order (exhaustive when small, else greedy),
+        priced for ``scope``'s groups."""
         if any(j.kind == "left_outer" for j in query.joins):
             candidates = [tuple(query.joins)]       # not reorderable
         elif len(query.joins) <= self.exhaustive_joins:
             candidates = self.enumerate_orders(query)
         else:
-            candidates = [self._greedy_order(query)]
-        priced = [self.price_order(query, order) for order in candidates]
+            candidates = [self._greedy_order(query, scope)]
+        priced = [self.price_order(query, order, scope=scope)
+                  for order in candidates]
         # Never worse than the textual left-deep order: it is always one of
         # the exhaustive candidates, and the greedy path falls back to it
         # if its pick prices above the baseline.
-        baseline = self.price_order(query, query.joins)
+        baseline = self.price_order(query, query.joins, scope=scope)
         best = min(priced, key=lambda p: p.est_total_s)
         return best if best.est_total_s <= baseline.est_total_s else baseline
 
-    def worst_order(self, query: Query) -> PhysicalPlan:
+    def worst_order(self, query: Query, *,
+                    scope: PlanScope = DISTINCT_GROUPS) -> PhysicalPlan:
         """The most expensive enumerated order (benchmark foil)."""
-        priced = [self.price_order(query, order)
+        priced = [self.price_order(query, order, scope=scope)
                   for order in self.enumerate_orders(query)]
         return max(priced, key=lambda p: p.est_total_s)
 
     # -- adaptive mid-pipeline re-optimization -------------------------------
     def reprice_remaining(self, query: Query, executed_order,
-                          remaining_order,
-                          observed_rows: dict) -> PhysicalPlan | None:
+                          remaining_order, observed_rows: dict, *,
+                          scope: PlanScope = DISTINCT_GROUPS
+                          ) -> PhysicalPlan | None:
         """Re-order not-yet-admitted stages from observed cardinalities.
 
         ``executed_order`` is the join-edge prefix the executor already
@@ -420,7 +428,7 @@ class JoinOrderOptimizer:
             return None
         incumbent = self.price_order(query, executed + remaining,
                                      observed_rows=observed_rows,
-                                     record=False)
+                                     record=False, scope=scope)
         # One stage per executed edge (cycle edges produce residual
         # filters, not stages — the executor does not replan those).
         if len(incumbent.stages) != len(executed) + len(remaining):
@@ -433,7 +441,7 @@ class JoinOrderOptimizer:
                 continue
             cand = self.price_order(query, executed + tail,
                                     observed_rows=observed_rows,
-                                    record=False)
+                                    record=False, scope=scope)
             if cand.est_total_s < best.est_total_s:
                 best, best_tail = cand, tail
         if best_tail == remaining:

@@ -1,10 +1,13 @@
 """Integration tests that need multiple XLA host devices — run in
 subprocesses so the main pytest process keeps its single device."""
 import json
+import os
 import subprocess
 import sys
 
 import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 CODE_COPROC = r"""
 import os
@@ -78,3 +81,70 @@ def test_coprocessor_real_two_groups():
 def test_dryrun_machinery_small_mesh():
     out = _run(CODE_DRYRUN)
     assert out["ok"] and out["flops"] > 0 and out["collectives"] > 0
+
+
+# The service and pipeline correctness checks, rerun where C and G are two
+# devices: there every SHJ and group-by scheme of the catalog is offered
+# (on one device both groups are the same device, and the planner offers
+# one single-group scheme).
+TWO_GROUP_CHECKS = [
+    "tests/test_engine.py::test_service_executes_mixed_workload_correctly",
+    "tests/test_engine.py::test_service_threaded_run_matches_oracle",
+    "tests/test_queries.py::test_star_pipeline_matches_reference",
+    "tests/test_queries.py::test_chain_pipeline_matches_reference",
+    "tests/test_queries.py::test_grouped_sink_consumes_view",
+    "tests/test_ops.py::test_service_groupby_query",
+    "tests/test_ops.py::test_groupby_query_through_service",
+    "tests/test_ops.py::test_semi_anti_star_matches_reference",
+    "tests/test_ops.py::test_coprocessed_groupby",
+]
+
+CODE_SHARED_PLANNER = r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
+import json, jax, numpy as np
+from repro.core import CoProcessor, join_oracle
+from repro.engine import JoinQueryService, QueryPlanner, make_workload
+devs = jax.devices()
+assert len(devs) == 2
+queries = make_workload("uniform", num_queries=4, base_tuples=8192, seed=3)
+pl = QueryPlanner()
+two = JoinQueryService(cp=CoProcessor(), planner=pl, num_workers=0)
+one = JoinQueryService(cp=CoProcessor(c_devices=devs[:1],
+                                      g_devices=devs[:1]),
+                       planner=pl, num_workers=0)
+alone = JoinQueryService(cp=CoProcessor(), planner=QueryPlanner(),
+                         num_workers=0)
+out = {"shared": [two.plan_scope.shared_groups,
+                  one.plan_scope.shared_groups]}
+for name, svc in (("one", one), ("two", two), ("alone", alone)):
+    outs = svc.run(queries)
+    out[name] = [f"{o.plan.algorithm}/{o.plan.scheme}" for o in outs]
+    out[name + "_ok"] = all(
+        np.array_equal(o.result.valid_pairs(), join_oracle(q.build, q.probe))
+        for o, q in zip(outs, queries))
+    svc.close()
+print(json.dumps(out))
+"""
+
+
+def test_service_and_pipelines_on_two_groups():
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=2")
+    r = subprocess.run(
+        [sys.executable, "-m", "pytest", "-q", "-p", "no:cacheprovider",
+         "-p", "no:randomly", *TWO_GROUP_CHECKS],
+        capture_output=True, text=True, timeout=600, env=env, cwd=ROOT)
+    assert r.returncode == 0, r.stdout[-3000:] + r.stderr[-2000:]
+
+
+def test_one_planner_plans_each_service_for_its_groups():
+    """One planner behind a two-group and a one-device service: each
+    service's plans are those of its own groups, and the two-group plans
+    are those of a planner no one-device service touched."""
+    out = _run(CODE_SHARED_PLANNER)
+    assert out["shared"] == [False, True]
+    assert out["one_ok"] and out["two_ok"] and out["alone_ok"]
+    assert out["two"] == out["alone"]
+    assert set(out["one"]) <= {"shj/GPU_ONLY", "phj/DD"}
+    assert set(out["two"]) - {"shj/GPU_ONLY"}
