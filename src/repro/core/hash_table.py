@@ -194,6 +194,17 @@ def probe_p2(table: HashTable, bkt: jax.Array):
     return table.bucket_key_start[bkt], table.bucket_key_count[bkt]
 
 
+def p3_trip_count(table: HashTable) -> jax.Array:
+    """Iterations p3's search needs: ``bit_length`` of the widest key list.
+
+    Each halving of a range of width ``w`` leaves at most ``w // 2``, so
+    ``bit_length(w)`` halvings converge it. A device scalar, computed
+    inside the probe's program and never pulled to the host.
+    """
+    widest = jnp.max(table.bucket_key_count, initial=0)
+    return jnp.int32(32) - jax.lax.clz(widest.astype(jnp.int32))
+
+
 def probe_p3(table: HashTable, key: jax.Array, kstart: jax.Array,
              kcount: jax.Array):
     """(p3) search the bucket's key list: bounded binary search.
@@ -201,10 +212,11 @@ def probe_p3(table: HashTable, key: jax.Array, kstart: jax.Array,
     The key list of a bucket is a sorted contiguous segment of ``ukeys``,
     so the paper's list walk becomes a binary search with log2(|keys in
     bucket|) random gathers per tuple (vs. the list walk's O(|keys|)).
+    The loop runs ``p3_trip_count(table)`` iterations: the halvings the
+    table's widest key list needs, which converge every lane's range.
     Returns the matching key-entry index (or -1) and its rid count.
     """
     n = table.ukeys.shape[0]
-    iters = max(1, int(n).bit_length() + 1)
     lo = kstart
     hi = kstart + kcount
     target = key.astype(jnp.uint32)
@@ -219,7 +231,7 @@ def probe_p3(table: HashTable, key: jax.Array, kstart: jax.Array,
         new_hi = jnp.where(go_right | (lo >= hi), hi, mid)
         return new_lo, new_hi
 
-    lo, hi = jax.lax.fori_loop(0, iters, body, (lo, hi))
+    lo, hi = jax.lax.fori_loop(0, p3_trip_count(table), body, (lo, hi))
     pos = jnp.clip(lo, 0, n - 1)
     found = (lo < kstart + kcount) & (table.ukeys[pos] == key)
     entry = jnp.where(found, pos, -1)

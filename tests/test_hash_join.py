@@ -1,4 +1,5 @@
 """Hash-join engine correctness: unit + hypothesis property tests."""
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -6,10 +7,12 @@ import pytest
 pytest.importorskip("hypothesis")
 from hypothesis import given, settings, strategies as st
 
-from repro.core import (Relation, build_hash_table, default_num_buckets,
-                        join_oracle, phj_join, probe_hash_table,
-                        probe_with_selectivity, shj_join, skewed_relation,
-                        uniform_relation, unique_relation)
+from repro.core import (CoProcessor, Relation, build_hash_table,
+                        default_num_buckets, join_oracle, murmur3_fmix32,
+                        phj_join, probe_hash_table, probe_with_selectivity,
+                        shj_join, skewed_relation, uniform_relation,
+                        unique_relation)
+from repro.core import hash_table as ht
 from repro.core.hash_table import merge_hash_tables
 from repro.core.partition import radix_partition, partition_ids
 from repro.core.phj import phj_coarse_join
@@ -97,6 +100,134 @@ def test_output_capacity_truncation():
     res = shj_join(b, p, num_buckets=16, max_out=100)
     assert int(res.count) == 100   # truncated, reported honestly
     assert (np.asarray(res.probe_rid[:100]) >= 0).all()
+
+
+# ---------------------------------------------------------------------------
+# p3's key-list search: bounded by the widest key list the table holds.
+# ---------------------------------------------------------------------------
+
+def _probe_p3_full_width(table, key, kstart, kcount):
+    """The search as it ran before its bound: log2 of the whole table."""
+    n = table.ukeys.shape[0]
+    lo, hi = kstart, kstart + kcount
+    target = key.astype(jnp.uint32)
+
+    def body(_, lh):
+        lo, hi = lh
+        mid = (lo + hi) >> 1
+        mid_key = table.ukeys[jnp.clip(mid, 0, n - 1)].astype(jnp.uint32)
+        go_right = (mid_key < target) & (lo < hi)
+        return (jnp.where(go_right, mid + 1, lo),
+                jnp.where(go_right | (lo >= hi), hi, mid))
+
+    lo, _ = jax.lax.fori_loop(0, max(1, int(n).bit_length() + 1), body,
+                              (lo, hi))
+    pos = jnp.clip(lo, 0, n - 1)
+    found = (lo < kstart + kcount) & (table.ukeys[pos] == key)
+    return (jnp.where(found, pos, -1),
+            jnp.where(found, table.key_rid_count[pos], 0))
+
+
+def _rel(keys):
+    keys = np.asarray(keys, np.int32)
+    return Relation(jnp.arange(keys.size, dtype=jnp.int32), jnp.asarray(keys))
+
+
+def _empty_table(num_buckets, capacity):
+    """A table with no key entries, as b3 leaves every unused slot."""
+    return ht.HashTable(
+        jnp.zeros((num_buckets,), jnp.int32),
+        jnp.zeros((num_buckets,), jnp.int32),
+        jnp.full((capacity,), ht.INVALID),
+        jnp.full((capacity,), capacity, jnp.int32),
+        jnp.zeros((capacity,), jnp.int32),
+        jnp.full((capacity,), ht.INVALID),
+        jnp.full((capacity,), ht.INVALID),
+        jnp.int32(0))
+
+
+def _p3_case(name):
+    """(build relation or None for empty, probe relation, buckets, least
+    trip count the case must reach)."""
+    rng = np.random.default_rng(sum(map(ord, name)))
+    if name == "uniform":          # ~4 tuples a bucket, the cells' sizing
+        b = rng.integers(0, 1 << 20, 4096)
+        p = np.concatenate([rng.choice(b, 3000), rng.integers(0, 1 << 20,
+                                                              1000)])
+        return _rel(b), _rel(p), default_num_buckets(4096), 1
+    if name == "four_wide_buckets":  # hundreds of keys a key list
+        b = rng.permutation(2000)
+        p = rng.integers(-50, 2100, 3000)
+        return _rel(b), _rel(p), 4, 9
+    if name == "empty":
+        return None, _rel(rng.integers(-4, 100, 500)), 64, 0
+    if name == "hot_key":          # one key with many rids widens no list
+        b = np.concatenate([np.full(700, 12345), rng.integers(0, 5000, 300)])
+        p = np.concatenate([np.full(40, 12345), rng.integers(0, 5000, 400)])
+        return _rel(rng.permutation(b)), _rel(p), 256, 1
+    if name == "pad_keys":         # PHJ's build pad -2 among real keys
+        b = np.concatenate([rng.integers(0, 3000, 900), np.full(124, -2)])
+        p = np.concatenate([rng.integers(0, 3000, 600),
+                            [-3, -1, -2, -7, np.iinfo(np.int32).min] * 8])
+        return _rel(b), _rel(p), 256, 1
+    raise KeyError(name)
+
+
+P3_CASES = ["uniform", "four_wide_buckets", "empty", "hot_key", "pad_keys"]
+
+
+@pytest.mark.parametrize("case", P3_CASES)
+def test_probe_p3_matches_full_width_search(case):
+    build, probe, nb, _ = _p3_case(case)
+    table = (_empty_table(nb, 512) if build is None
+             else build_hash_table(build, nb))
+    kstart, kcount = ht.probe_p2(table, ht.probe_p1(probe.key, nb))
+    entry, nmatch = jax.jit(ht.probe_p3)(table, probe.key, kstart, kcount)
+    ref_entry, ref_nmatch = _probe_p3_full_width(table, probe.key, kstart,
+                                                 kcount)
+    assert np.array_equal(entry, ref_entry)
+    assert np.array_equal(nmatch, ref_nmatch)
+    if build is None:
+        assert int(table.num_keys) == 0 and not np.asarray(nmatch).any()
+        return
+    exp = join_oracle(build, probe)
+    res = probe_hash_table(probe, table, max(64, len(exp) + 8))
+    assert np.array_equal(res.valid_pairs(), exp)
+
+
+@pytest.mark.parametrize("case", P3_CASES)
+def test_p3_trip_count_is_widest_key_list(case):
+    build, _, nb, least = _p3_case(case)
+    table = (_empty_table(nb, 512) if build is None
+             else build_hash_table(build, nb))
+    widest = int(np.asarray(table.bucket_key_count).max())
+    trips = int(jax.jit(ht.p3_trip_count)(table))
+    assert trips == widest.bit_length()
+    assert trips >= least
+
+
+def test_phj_coprocess_colliding_keys_pair_exact():
+    """CoProcessor.phj's owned join on keys that share a bucket: the
+    share's key-list bound passes 4 and the pairs stay exact."""
+    bits_per_pass, num_passes, shj_bits = 3, 2, 2
+    buckets = 1 << (bits_per_pass * num_passes + shj_bits)
+    cand = np.arange(1 << 17, dtype=np.int32)
+    h = np.asarray(murmur3_fmix32(jnp.asarray(cand)))
+    colliding = cand[(h & (buckets - 1)) == 0][:300]
+    rng = np.random.default_rng(21)
+    bkeys = np.concatenate([colliding, rng.integers(1 << 17, 1 << 20, 1700)])
+    pkeys = np.concatenate([rng.choice(bkeys, 3000),
+                            rng.integers(0, 1 << 20, 1096)])
+    b, p = _rel(rng.permutation(bkeys)), _rel(pkeys)
+    # The owned join's bucket is a bit permutation of the hash's low
+    # (total + shj) bits, so it has the widest list build_hash_table has.
+    assert int(ht.p3_trip_count(build_hash_table(b, buckets))) > 4
+    exp = join_oracle(b, p)
+    res, _ = CoProcessor().phj(b, p, bits_per_pass=bits_per_pass,
+                               num_passes=num_passes, shj_bits=shj_bits,
+                               max_out=8192, partition_ratio=0.25,
+                               join_ratio=0.5)
+    assert np.array_equal(res.valid_pairs(), exp)
 
 
 # ---------------------------------------------------------------------------
